@@ -3,14 +3,15 @@
     PYTHONPATH=src python -m benchmarks.run [--quick]
 
 Emits ``name,us_per_call,derived`` CSV rows (also collected in
-``benchmarks.common.ROWS``).
+``benchmarks.common.ROWS``).  Every entry runs even when one fails; the
+exit status is non-zero when any entry errored.
 """
 import argparse
 import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="fewer training steps (CI mode)")
@@ -23,6 +24,7 @@ def main() -> None:
     from .common import emit
 
     t0 = time.time()
+    failed = []
     for name, fn in [
         ("table1_mse", lambda: table1_mse.run(steps=min(steps, 120))),
         ("fig1_expdist", lambda: fig1_expdist.run(steps=min(steps, 120))),
@@ -41,9 +43,13 @@ def main() -> None:
             fn()
         except Exception as e:  # pragma: no cover
             emit(f"{name}_ERROR", 0.0, repr(e)[:120])
+            failed.append(name)
         emit(f"{name}_wall", (time.time() - t) * 1e6, "")
     emit("benchmarks_total_wall", (time.time() - t0) * 1e6, "")
+    if failed:
+        print(f"errored: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

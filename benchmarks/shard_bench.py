@@ -4,7 +4,7 @@ Wall-clock on forced host devices is NOT pod performance (every "device"
 is a slice of one CPU); what transfers are the STRUCTURAL rows this file
 emits — per-device store/cache bytes (does the memory actually split?),
 dispatch counts (sharding must not change the schedule), and the
-token-for-token parity bit (GSPMD partitioning is semantics-preserving).
+token-for-token parity bit (sharding must not change the tokens).
 Emits ``BENCH_shard.json`` (override with ``$BENCH_SHARD_JSON``).
 
 Run under forced host devices:

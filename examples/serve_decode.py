@@ -19,6 +19,7 @@ from repro.configs.base import get_config
 from repro.core.policy import MXSF_INFER
 from repro.launch.mesh import make_test_mesh
 from repro.models import model as M
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve.engine import ServeEngine
 
 
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--no-pack", action="store_true",
                     help="keep full-precision weights (re-quantize per call)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     policy = MXSF_INFER.replace(block_1d=16, kv_cache_fmt="mxsf")

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import blocking as B
+from . import sharding as shd
 from .policy import QuantPolicy
 
 __all__ = ["mx_dot", "mx_einsum", "qdq_along", "count_quant_passes",
@@ -371,15 +372,46 @@ def _qt_zero_cot(qt: B.QuantizedTensor) -> B.QuantizedTensor:
                              qt.block, qt.shape, qt.dtype)
 
 
-def _packed_fwd(policy: QuantPolicy, xm, qw: B.QuantizedTensor):
+def _fused_packed(policy: QuantPolicy, xm, qw: B.QuantizedTensor,
+                  tp_in: bool):
+    """The fused kernel against resident codes; under a mesh context it
+    runs shard-local (``sharding.shard_local``): rows over the DP axes and
+    the weight's output dim over TP, or with ``tp_in`` (row-parallel
+    weights: wo, wd) its contraction dim over TP — in whole MX blocks —
+    and the partial products summed over TP."""
+    from ..kernels import ops as K
+    xblk, wblk = _pol_blocks(policy)
+    call = lambda x, c, s: K.mxsf_fused_matmul(x, c, s, xblk, wblk,
+                                               emit_codes=False)
+    if shd.active() is None:
+        return call(xm, qw.codes, qw.scale_e8m0)
+    P = jax.sharding.PartitionSpec
+    rows = shd.split_axes(xm.shape[0], "batch")
+    if not tp_in:
+        tp = shd.split_axes(qw.scale_e8m0.shape[1], "hidden")
+        return shd.shard_local(call, (P(rows, None), P(None, tp),
+                                      P(None, tp)),
+                               P(rows, tp))(xm, qw.codes, qw.scale_e8m0)
+    tp = shd.split_axes(qw.scale_e8m0.shape[0], "hidden")
+    kw = qw.codes.shape[0]
+    if xm.shape[1] < kw:  # split x's K exactly like the block-padded codes
+        xm = jnp.pad(xm, ((0, 0), (0, kw - xm.shape[1])))
+
+    def local(x, c, s):
+        y = call(x, c, s)
+        return y if tp is None else jax.lax.psum(y, tp)
+
+    return shd.shard_local(local, (P(rows, tp), P(tp, None), P(tp, None)),
+                           P(rows, None))(xm, qw.codes, qw.scale_e8m0)
+
+
+def _packed_fwd(policy: QuantPolicy, xm, qw: B.QuantizedTensor,
+                tp_in: bool = False):
     """Forward against resident codes: ZERO weight-quantize dispatches."""
     k, n = qw.shape
     if policy.use_pallas and xm.shape[0] > 0 and k > 0 and n > 0:
-        from ..kernels import ops as K
-        xblk, wblk = _pol_blocks(policy)
         _tick()  # x quantized on the fly; w codes are resident, no dispatch
-        y = K.mxsf_fused_matmul(xm, qw.codes, qw.scale_e8m0, xblk, wblk,
-                                emit_codes=False)
+        y = _fused_packed(policy, xm, qw, tp_in)
         return y[:, :n].astype(jnp.result_type(xm.dtype, qw.dtype))
     wq = B.dequantize(qw)
     if not policy.enabled:
@@ -416,23 +448,23 @@ def _pallas_packed_dx(policy: QuantPolicy, qw: B.QuantizedTensor, gm):
     return dx[:m, :k]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _mx_dot_packed(policy: QuantPolicy, x: jax.Array,
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _mx_dot_packed(policy: QuantPolicy, tp_in: bool, x: jax.Array,
                    qw: B.QuantizedTensor) -> jax.Array:
     xm, lead = _flatten_lead(x)
-    y = _packed_fwd(policy, xm, qw)
+    y = _packed_fwd(policy, xm, qw, tp_in)
     return y.reshape(*lead, qw.shape[-1])
 
 
-def _mx_dot_packed_fwd(policy: QuantPolicy, x, qw):
+def _mx_dot_packed_fwd(policy: QuantPolicy, tp_in: bool, x, qw):
     # the residual IS the resident store: no activation codes are emitted
     # (packed weights are frozen -> dw is a symbolic zero -> x is unused)
     xm, lead = _flatten_lead(x)
-    y = _packed_fwd(policy, xm, qw)
+    y = _packed_fwd(policy, xm, qw, tp_in)
     return y.reshape(*lead, qw.shape[-1]), qw
 
 
-def _mx_dot_packed_bwd(policy: QuantPolicy, qw, g):
+def _mx_dot_packed_bwd(policy: QuantPolicy, tp_in: bool, qw, g):
     gm, lead = _flatten_lead(g)
     k = qw.shape[0]
     if policy.use_pallas and gm.shape[0] > 0 and gm.shape[1] > 0 and k > 0:
@@ -447,19 +479,22 @@ def _mx_dot_packed_bwd(policy: QuantPolicy, qw, g):
 _mx_dot_packed.defvjp(_mx_dot_packed_fwd, _mx_dot_packed_bwd)
 
 
-def mx_dot(x: jax.Array, w, policy: QuantPolicy) -> jax.Array:
+def mx_dot(x: jax.Array, w, policy: QuantPolicy,
+           tp_in: bool = False) -> jax.Array:
     """Quantized ``x @ w`` (x: (..., K), w: (K, N)) per the MX policy.
 
     ``w`` may be a raw array (quantized per call) or a resident
     ``blocking.QuantizedTensor`` from the pack-once store
     (``core/packed_store.py``) — the packed path performs zero
     weight-quantize dispatches and treats the weight as frozen (its
-    cotangent is a symbolic zero).
+    cotangent is a symbolic zero).  ``tp_in`` marks a row-parallel weight
+    (contraction dim over the TP axis, ``launch/mesh.py``): the kernel
+    datapath under a mesh then sums partial products over TP.
     """
     if isinstance(w, B.QuantizedTensor):
         qw = _layer_qt(w)
         _check_packed(policy, qw)
-        return _mx_dot_packed(policy, x, qw)
+        return _mx_dot_packed(policy, bool(tp_in), x, qw)
     if not policy.enabled:
         return jnp.matmul(x, w)
     return _mx_dot(policy, x, w)

@@ -76,6 +76,34 @@ def spec_for(shape: Sequence[int], roles: Sequence[Optional[str]],
     return P(*spec)
 
 
+def split_axes(dim: int, role: str):
+    """Mesh axes a dim of size ``dim`` splits over for ``role`` ('batch'
+    -> the DP axes, any TP role -> the TP axis), or None when there is no
+    context, no such axis, or it does not divide ``dim``."""
+    ctx = _CTX.get()
+    if ctx is None:
+        return None
+    if role == "batch":
+        ok = ctx["dp"] and dim % ctx["dp_size"] == 0
+        return ctx["dp"] if ok else None
+    ok = ctx["tp"] and dim % ctx["tp_size"] == 0
+    return ctx["tp"] if ok else None
+
+
+def shard_local(fn, in_specs, out_specs):
+    """``fn`` run once per shard under the active mesh (``jax.shard_map``).
+
+    The TPU compiler cannot partition a Pallas kernel (a Mosaic custom
+    call) the way GSPMD partitions XLA ops, so a kernel on a sharded step
+    runs shard-local with explicit specs; operands laid out otherwise are
+    resharded to them.  Without a context ``fn`` is returned as is."""
+    ctx = _CTX.get()
+    if ctx is None:
+        return fn
+    return jax.shard_map(fn, mesh=ctx["mesh"], in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def constrain(x: jax.Array, *roles: Optional[str]) -> jax.Array:
     """with_sharding_constraint by role; no-op without a mesh context."""
     spec = spec_for(x.shape, roles)

@@ -1,4 +1,5 @@
-"""Bit-level float helpers shared by the Pallas kernels.
+"""Bit-level float helpers and the E8M0 scale layout shared by the Pallas
+kernels.
 
 TPU Pallas has no frexp/ldexp lowering, so exponent extraction and
 power-of-two construction are done by bit-casting — identical semantics in
@@ -6,10 +7,15 @@ interpret mode (CPU validation) and on real TPUs.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-__all__ = ["flog2", "exp2i", "rne", "scale_by_exp2", "broadcast_block_scale",
+__all__ = ["flog2", "exp2i", "rne", "scale_by_exp2", "block_exponents",
+           "expand_scales", "scale_block_spec", "to_kernel_scales",
+           "from_kernel_scales", "scale_shape", "tile_multiples",
            "decode_mxsf", "encode_mxsf"]
 
 
@@ -44,11 +50,108 @@ def scale_by_exp2(x: jax.Array, e: jax.Array) -> jax.Array:
     return x * exp2i(e1) * exp2i(e - e1)
 
 
-def broadcast_block_scale(se: jax.Array, bm: int, bk: int, tm: int, tk: int):
-    """Block-grid scale exponents -> per-element (tm, tk) map."""
-    gm, gk = tm // bm, tk // bk
-    se = se.reshape(gm, 1, gk, 1)
-    return jnp.broadcast_to(se, (gm, bm, gk, bk)).reshape(tm, tk)
+# ---------------------------------------------------------------------------
+# E8M0 scales at the kernel boundary
+# ---------------------------------------------------------------------------
+#
+# Mosaic blocks the last two dims of every operand in (8, 128) units, so a
+# block-grid scale tile (tm/bm, tk/bk) -- (256, 16) for 1x32 row blocks --
+# is refused.  The kernels therefore see scales in a lane-dense layout:
+#
+#   * bk == 1 (blocks run down the rows: the (B, 1) weight layout): the
+#     block grid itself, (R/br, C); the pack-once store feeds it as-is.
+#   * bk > 1 (blocks run along the lanes: 1xB activation rows, TxT tiles):
+#     the transposed grid with one column per operand row, (C/bc, R); for
+#     TxT tiles each column repeats its tile's exponent over the tile's rows.
+#
+# Inside a kernel, block reductions and broadcasts only ever split or merge
+# the sublane dim in whole groups (the 4-D (tm, tk) -> (gm, bm, gk, bk)
+# reshape is an "unsupported shape cast"); lane blocks are moved onto the
+# sublanes by a 2-D transpose first.
+
+
+def _group_max_rows(a: jax.Array, b: int) -> jax.Array:
+    """Max over aligned groups of ``b`` rows: (n, w) -> (n // b, w)."""
+    if b == 1:
+        return a
+    n, w = a.shape
+    return a.reshape(n // b, b, w).max(axis=1)
+
+
+def _repeat_rows(s: jax.Array, b: int) -> jax.Array:
+    """Every row ``b`` times: (g, w) -> (g * b, w)."""
+    if b == 1:
+        return s
+    g, w = s.shape
+    return jnp.broadcast_to(s[:, None, :], (g, b, w)).reshape(g * b, w)
+
+
+def block_exponents(x: jax.Array, bm: int, bk: int):
+    """Shared block exponents of an f32 tile.
+
+    Returns ``(se, se_el)``: ``se`` in the kernel scale layout (see above)
+    and ``se_el`` the (tm, tk) per-element map.  All-zero blocks get -127,
+    matching ``formats.shared_exponent``.
+    """
+    a = jnp.abs(x)
+    if bk == 1:
+        amax = _group_max_rows(a, bm)                       # (tm/bm, tk)
+    else:
+        amax = _group_max_rows(a.T, bk)                     # (tk/bk, tm)
+        if bm > 1:
+            amax = _repeat_rows(_group_max_rows(amax.T, bm), bm).T
+    se = jnp.where(amax > 0, flog2(amax), -127)
+    return se, expand_scales(se, bm, bk)
+
+
+def expand_scales(se: jax.Array, bm: int, bk: int) -> jax.Array:
+    """Kernel-layout block exponents -> the (tm, tk) per-element map."""
+    if bk == 1:
+        return _repeat_rows(se, bm)
+    return _repeat_rows(se, bk).T
+
+
+def scale_block_spec(block, tr: int, tc: int, index_map):
+    """BlockSpec of the scales of an operand tiled (tr, tc) with MX block
+    ``block``; ``index_map`` is the operand's own (grid -> (I, J))."""
+    br, bc = block
+    if bc == 1:
+        return pl.BlockSpec((tr // br, tc), index_map)
+    return pl.BlockSpec((tc // bc, tr),
+                        lambda *g: tuple(reversed(index_map(*g))))
+
+
+def tile_multiples(*blocks):
+    """(row, col) multiples a compiled tile of an operand needs: (8, 128)
+    for its codes, and for the kernel-layout scales of each MX block in
+    ``blocks`` whatever keeps their blocks on the (8, 128) rule too."""
+    r, c = 8, 128
+    for br, bc in blocks:
+        r = math.lcm(r, 8 * br if bc == 1 else 128)
+        c = math.lcm(c, 128 if bc == 1 else 8 * bc)
+    return r, c
+
+
+def scale_shape(block, rows: int, cols: int):
+    """Shape of the kernel-layout scale array of a (rows, cols) operand."""
+    br, bc = block
+    return (rows // br, cols) if bc == 1 else (cols // bc, rows)
+
+
+def to_kernel_scales(s: jax.Array, block) -> jax.Array:
+    """Block-grid scales (``QuantizedTensor.scale_e8m0``) -> kernel layout."""
+    br, bc = block
+    if bc == 1:
+        return s
+    return jnp.repeat(s, br, axis=0).T
+
+
+def from_kernel_scales(s: jax.Array, block) -> jax.Array:
+    """Kernel-layout scales -> the block grid."""
+    br, bc = block
+    if bc == 1:
+        return s
+    return s.T[::br]
 
 
 def rne(x: jax.Array) -> jax.Array:

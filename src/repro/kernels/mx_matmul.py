@@ -19,8 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import broadcast_block_scale as _broadcast_scale
-from .common import decode_mxsf, exp2i
+from .common import decode_mxsf, exp2i, expand_scales, scale_block_spec
 
 SCALE_BIAS = 127
 
@@ -31,12 +30,10 @@ def _matmul_kernel(xc_ref, xs_ref, wc_ref, ws_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tm, tk = xc_ref.shape
-    tk2, tn = wc_ref.shape
     xse = xs_ref[...].astype(jnp.int32) - SCALE_BIAS
     wse = ws_ref[...].astype(jnp.int32) - SCALE_BIAS
-    xv = decode_mxsf(xc_ref[...]) * exp2i(_broadcast_scale(xse, *xblk, tm, tk))
-    wv = decode_mxsf(wc_ref[...]) * exp2i(_broadcast_scale(wse, *wblk, tk2, tn))
+    xv = decode_mxsf(xc_ref[...]) * exp2i(expand_scales(xse, *xblk))
+    wv = decode_mxsf(wc_ref[...]) * exp2i(expand_scales(wse, *wblk))
     acc_ref[...] += jnp.dot(xv, wv, preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == nk - 1)
@@ -56,6 +53,7 @@ def mxsf_matmul_pallas(x_codes, x_scales, w_codes, w_scales, *,
 
     ``xblk``/``wblk`` are the MX block shapes of each operand: (1, B)/(B, 1)
     for 1D inference layout, (T, T)/(T, T) for the 2D training tiles.
+    Scales are in the kernel layout (``common.to_kernel_scales``).
     """
     m, k = x_codes.shape
     k2, n = w_codes.shape
@@ -64,14 +62,16 @@ def mxsf_matmul_pallas(x_codes, x_scales, w_codes, w_scales, *,
     assert m % tm == 0 and n % tn == 0 and k % tk == 0
     nk = k // tk
     kernel = functools.partial(_matmul_kernel, nk=nk, xblk=xblk, wblk=wblk)
+    x_tile = lambda i, j, kk: (i, kk)
+    w_tile = lambda i, j, kk: (kk, j)
     return pl.pallas_call(
         kernel,
         grid=(m // tm, n // tn, nk),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tm // xblk[0], tk // xblk[1]), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tk, tn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((tk // wblk[0], tn // wblk[1]), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((tm, tk), x_tile),
+            scale_block_spec(xblk, tm, tk, x_tile),
+            pl.BlockSpec((tk, tn), w_tile),
+            scale_block_spec(wblk, tk, tn, w_tile),
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),

@@ -42,8 +42,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import (broadcast_block_scale, decode_mxsf, encode_mxsf, exp2i,
-                     flog2, scale_by_exp2)
+from .common import (block_exponents, decode_mxsf, encode_mxsf, exp2i,
+                     expand_scales, scale_block_spec, scale_by_exp2,
+                     scale_shape)
 
 SCALE_BIAS = 127
 
@@ -60,16 +61,10 @@ def _fused_kernel(x_ref, wc_ref, ws_ref, o_ref, *rest, nk: int, xblk, wblk,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    tm, tk = x.shape
-    tk2, tn = wc_ref.shape
 
     if quantize_lhs:
         # --- MXSF Converter, fused into the matmul prologue ---------------
-        bm, bk = xblk
-        gm, gk = tm // bm, tk // bk
-        amax = jnp.abs(x).reshape(gm, bm, gk, bk).max(axis=(1, 3))
-        se = jnp.where(amax > 0, flog2(amax), -127)
-        se_el = broadcast_block_scale(se, bm, bk, tm, tk)
+        se, se_el = block_exponents(x, *xblk)
         codes = encode_mxsf(scale_by_exp2(x, -se_el))
         # decode-in-MAC: reconstruct through the byte codec so the operand
         # is bit-identical to the packed reference path
@@ -88,8 +83,7 @@ def _fused_kernel(x_ref, wc_ref, ws_ref, o_ref, *rest, nk: int, xblk, wblk,
         xv = x
 
     wse = ws_ref[...].astype(jnp.int32) - SCALE_BIAS
-    wv = decode_mxsf(wc_ref[...]) * exp2i(
-        broadcast_block_scale(wse, *wblk, tk2, tn))
+    wv = decode_mxsf(wc_ref[...]) * exp2i(expand_scales(wse, *wblk))
     acc_ref[...] += jnp.dot(xv, wv, preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == nk - 1)
@@ -109,6 +103,7 @@ def mxsf_fused_matmul_pallas(x, w_codes, w_scales, *,
     """Unquantized (M,K) x @ packed (K,N) w -> f32 (M,N).
 
     Returns ``y`` or, with ``emit_codes``, ``(y, x_codes, x_scales)``.
+    Scales in and out are in the kernel layout (``common.scale_shape``).
     Shapes must be tile multiples; ``ops.mxsf_fused_matmul`` pads/crops.
     """
     m, k = x.shape
@@ -123,26 +118,26 @@ def mxsf_fused_matmul_pallas(x, w_codes, w_scales, *,
     kernel = functools.partial(_fused_kernel, nk=nk, xblk=xblk, wblk=wblk,
                                quantize_lhs=quantize_lhs,
                                emit_codes=emit_codes)
+    x_tile = lambda i, j, kk: (i, kk)
+    w_tile = lambda i, j, kk: (kk, j)
     out_shape = [jax.ShapeDtypeStruct((m, n), jnp.float32)]
     out_specs = [pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j))]
     if emit_codes:
         out_shape += [
             jax.ShapeDtypeStruct((m, k), jnp.uint8),
-            jax.ShapeDtypeStruct((m // xblk[0], k // xblk[1]), jnp.uint8),
+            jax.ShapeDtypeStruct(scale_shape(xblk, m, k), jnp.uint8),
         ]
         out_specs += [
-            pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tm // xblk[0], tk // xblk[1]),
-                         lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((tm, tk), x_tile),
+            scale_block_spec(xblk, tm, tk, x_tile),
         ]
     out = pl.pallas_call(
         kernel,
         grid=(m // tm, n // tn, nk),
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tk, tn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((tk // wblk[0], tn // wblk[1]),
-                         lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((tm, tk), x_tile),
+            pl.BlockSpec((tk, tn), w_tile),
+            scale_block_spec(wblk, tk, tn, w_tile),
         ],
         out_specs=out_specs,
         out_shape=out_shape,

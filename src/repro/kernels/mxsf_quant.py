@@ -18,8 +18,10 @@ Two kernels share the converter body:
     same exp2i product ``blocking.dequantize`` uses and the encode is the
     shared converter.
 
-MXU alignment: TK is a multiple of 128 (lane dim), TM a multiple of 8
-(sublane) — see BlockSpec choices in ``ops.py``.
+Scales cross the kernel boundary in the lane-dense layout of
+``common.py`` (``to_kernel_scales`` / ``from_kernel_scales``); ``ops.py``
+converts to and from the ``QuantizedTensor`` block grid and picks tiles
+the TPU's (8, 128) block rule accepts.
 """
 from __future__ import annotations
 
@@ -29,8 +31,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import (broadcast_block_scale, decode_mxsf, encode_mxsf, exp2i,
-                     flog2, scale_by_exp2)
+from .common import (block_exponents, decode_mxsf, encode_mxsf, exp2i,
+                     expand_scales, scale_block_spec, scale_by_exp2,
+                     scale_shape)
 
 SCALE_BIAS = 127
 
@@ -52,15 +55,11 @@ def _encode_tile(x, bm: int, bk: int):
 
     Used by both the raw-input quantize kernel and the packed->packed
     requantize kernel, so converter fixes (subnormal flog2, -0.0 signs, ...)
-    apply to both by construction.
+    apply to both by construction.  Scale bytes come out in the kernel
+    scale layout (``common.block_exponents``).
     """
-    tm, tk = x.shape
-    gm, gk = tm // bm, tk // bk
-    # block max -> shared exponent
-    amax = jnp.abs(x).reshape(gm, bm, gk, bk).max(axis=(1, 3))
-    se = jnp.where(amax > 0, flog2(amax), -127)
+    se, se_el = block_exponents(x, bm, bk)
     # scale each element by 2^-S_e and encode
-    se_el = broadcast_block_scale(se, bm, bk, tm, tk)
     xa = scale_by_exp2(x, -se_el)  # exact even for |S_e| > 126 (subnormal amax)
     codes = encode_mxsf(xa)
     scales = jnp.clip(se + SCALE_BIAS, 0, 255).astype(jnp.uint8)
@@ -76,8 +75,9 @@ def mxsf_quantize_pallas(x: jax.Array, *, block=(1, 32), tm: int = 256,
                          tk: int = 512, interpret: bool = False):
     """Quantize a 2D f32/bf16 array to MXSF codes + E8M0 scales.
 
-    Returns ``(codes[M, K] uint8, scales[M/bm, K/bk] uint8)``.
-    Shapes must be multiples of the tile; ``ops.py`` handles padding.
+    Returns ``(codes[M, K] uint8, scales)`` with the scales in the kernel
+    layout (``common.scale_shape``).  Shapes must be multiples of the tile;
+    ``ops.py`` handles padding and the layout conversion.
     """
     global _TRACE_COUNT
     _TRACE_COUNT += 1
@@ -96,17 +96,18 @@ def _mxsf_quantize_jit(x: jax.Array, *, block, tm: int, tk: int,
     assert tm % bm == 0 and tk % bk == 0, (tm, tk, block)
     grid = (m // tm, k // tk)
     kernel = functools.partial(_quant_kernel, bm=bm, bk=bk)
+    tile = lambda i, j: (i, j)
     codes, scales = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((tm, tk), lambda i, j: (i, j))],
+        in_specs=[pl.BlockSpec((tm, tk), tile)],
         out_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j: (i, j)),
-            pl.BlockSpec((tm // bm, tk // bk), lambda i, j: (i, j)),
+            pl.BlockSpec((tm, tk), tile),
+            scale_block_spec(block, tm, tk, tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k), jnp.uint8),
-            jax.ShapeDtypeStruct((m // bm, k // bk), jnp.uint8),
+            jax.ShapeDtypeStruct(scale_shape(block, m, k), jnp.uint8),
         ],
         interpret=interpret,
     )(x)
@@ -115,12 +116,10 @@ def _mxsf_quantize_jit(x: jax.Array, *, block, tm: int, tk: int,
 
 def _requant_kernel(c_ref, s_ref, codes_ref, scale_ref, *, from_block,
                     to_block):
-    tm, tk = c_ref.shape
     # decode the resident codes in VMEM — same exp2i product as
     # blocking.dequantize, so the value set is bit-identical
     fse = s_ref[...].astype(jnp.int32) - SCALE_BIAS
-    x = decode_mxsf(c_ref[...]) * exp2i(
-        broadcast_block_scale(fse, *from_block, tm, tk))
+    x = decode_mxsf(c_ref[...]) * exp2i(expand_scales(fse, *from_block))
     # re-encode under the new block orientation (the shared converter body)
     codes_ref[...], scale_ref[...] = _encode_tile(x, *to_block)
 
@@ -133,7 +132,8 @@ def mxsf_requantize_pallas(codes: jax.Array, scales: jax.Array, *,
 
     One dispatch, 1-byte traffic both ways — replaces the
     ``dequantize`` → f32 HBM → ``quantize`` pair.  Returns
-    ``(codes[M, K], scales[M/bm', K/bk'])`` for ``to_block = (bm', bk')``.
+    ``(codes[M, K], scales)`` for ``to_block``; scales in and out are in
+    the kernel layout (``common.scale_shape``).
     Shapes must be multiples of the tile and of both blocks;
     ``ops.mxsf_requantize`` handles padding.
     """
@@ -158,23 +158,21 @@ def _mxsf_requantize_jit(codes: jax.Array, scales: jax.Array, *,
     grid = (m // tm, k // tk)
     kernel = functools.partial(_requant_kernel, from_block=tuple(from_block),
                                to_block=tuple(to_block))
+    tile = lambda i, j: (i, j)
     out_codes, out_scales = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j: (i, j)),
-            pl.BlockSpec((tm // from_block[0], tk // from_block[1]),
-                         lambda i, j: (i, j)),
+            pl.BlockSpec((tm, tk), tile),
+            scale_block_spec(from_block, tm, tk, tile),
         ],
         out_specs=[
-            pl.BlockSpec((tm, tk), lambda i, j: (i, j)),
-            pl.BlockSpec((tm // to_block[0], tk // to_block[1]),
-                         lambda i, j: (i, j)),
+            pl.BlockSpec((tm, tk), tile),
+            scale_block_spec(to_block, tm, tk, tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k), jnp.uint8),
-            jax.ShapeDtypeStruct((m // to_block[0], k // to_block[1]),
-                                 jnp.uint8),
+            jax.ShapeDtypeStruct(scale_shape(to_block, m, k), jnp.uint8),
         ],
         interpret=interpret,
     )(codes, scales)

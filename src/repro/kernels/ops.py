@@ -1,9 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles padding to tile multiples and backend dispatch: on TPU the kernels
-run compiled; everywhere else they run in ``interpret=True`` mode (Python
-emulation of the kernel body), which is how this CPU container validates
-them.
+Handles padding to tile multiples, the scale-layout conversion at the
+kernel boundary (``common.to_kernel_scales``) and backend dispatch: on TPU
+the kernels run compiled; on the CPU they run in ``interpret=True`` mode
+(Python emulation of the kernel body), which is how the tests validate
+them.  Any other platform is an error, never a silent fallback.
+
+Compiled tiles follow the TPU's block rule (the last two dims of every
+block a multiple of (8, 128) or the whole array); interpret mode keeps the
+caller's tiles, so small-shape tests still cover multi-tile grids.
 
 Padding is always with zeros: zero elements never raise a block amax, zero
 codes decode to exactly 0.0, and adding 0.0 terms to an f32 accumulation is
@@ -18,6 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .common import from_kernel_scales, tile_multiples, to_kernel_scales
 from .mx_matmul import mxsf_matmul_pallas
 from .mxsf_attention import mxsf_flash_attention, per_row_scalar
 from .mxsf_fused_matmul import mxsf_fused_matmul_pallas
@@ -25,19 +31,31 @@ from .mxsf_quant import mxsf_quantize_pallas, mxsf_requantize_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    platform = jax.default_backend()
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(f"the MXSF Pallas kernels run compiled on TPU or "
+                           f"interpreted on CPU; got platform {platform!r}")
+    return platform == "cpu"
 
 
 def _ceil_to(n: int, mult: int) -> int:
     return -(-n // mult) * mult
 
 
-def _tile_for(dim: int, tile: int, block: int):
+def _tile_for(dim: int, tile: int, block: int, align: int = 1):
     """Effective tile edge and padded dim: the tile shrinks to the
-    block-padded dim for small inputs, the dim pads up to a tile multiple."""
-    t = min(tile, _ceil_to(dim, block))
+    block-padded dim for small inputs (a whole-extent block is always
+    legal), else rounds up to a multiple of ``align``; the dim pads up to
+    a tile multiple."""
+    t = min(_ceil_to(tile, math.lcm(block, align)), _ceil_to(dim, block))
     assert t % block == 0, (dim, tile, block)
     return t, _ceil_to(dim, t)
+
+
+def _align(interpret: bool, *blocks):
+    """(row, col) tile multiples for compiled kernels (``common.
+    tile_multiples``); interpret mode takes any tile."""
+    return (1, 1) if interpret else tile_multiples(*blocks)
 
 
 def _pad2d(x, m_to, k_to, fill=0):
@@ -57,11 +75,14 @@ def mxsf_quantize(x: jax.Array, block=(1, 32), tm: int = 256, tk: int = 512):
     """
     m, k = x.shape
     bm, bk = block
-    tm, mp = _tile_for(m, tm, bm)
-    tk, kp = _tile_for(k, tk, bk)
+    interpret = _interpret()
+    ar, ac = _align(interpret, block)
+    tm, mp = _tile_for(m, tm, bm, ar)
+    tk, kp = _tile_for(k, tk, bk, ac)
     codes, scales = mxsf_quantize_pallas(_pad2d(x, mp, kp),
                                          block=tuple(block), tm=tm, tk=tk,
-                                         interpret=_interpret())
+                                         interpret=interpret)
+    scales = from_kernel_scales(scales, block)
     mb, kb = _ceil_to(m, bm), _ceil_to(k, bk)
     return codes[:mb, :kb], scales[: mb // bm, : kb // bk]
 
@@ -83,13 +104,16 @@ def mxsf_requantize(codes, scales, from_block=(32, 1), to_block=(1, 32),
     assert m % fbm == 0 and k % fbk == 0, (codes.shape, from_block)
     bm = math.lcm(fbm, tbm)
     bk = math.lcm(fbk, tbk)
-    tm, mp = _tile_for(m, tm, bm)
-    tk, kp = _tile_for(k, tk, bk)
+    interpret = _interpret()
+    ar, ac = _align(interpret, from_block, to_block)
+    tm, mp = _tile_for(m, tm, bm, ar)
+    tk, kp = _tile_for(k, tk, bk, ac)
     c = _pad2d(codes, mp, kp)
-    s = _pad2d(scales, mp // fbm, kp // fbk)
+    s = to_kernel_scales(_pad2d(scales, mp // fbm, kp // fbk), from_block)
     oc, os_ = mxsf_requantize_pallas(c, s, from_block=tuple(from_block),
                                      to_block=tuple(to_block), tm=tm, tk=tk,
-                                     interpret=_interpret())
+                                     interpret=interpret)
+    os_ = from_kernel_scales(os_, to_block)
     mb, kb = _ceil_to(m, tbm), _ceil_to(k, tbk)
     return oc[:mb, :kb], os_[: mb // tbm, : kb // tbk]
 
@@ -104,18 +128,23 @@ def mxsf_matmul(x_codes, x_scales, w_codes, w_scales, xblk=(1, 32),
     m, k = x_codes.shape
     k2, n = w_codes.shape
     assert k == k2, (k, k2)
-    tm, mp = _tile_for(m, tm, xblk[0])
-    tn, np_ = _tile_for(n, tn, wblk[1])
+    interpret = _interpret()
+    xr, xc = _align(interpret, xblk)
+    wr, wc = _align(interpret, wblk)
+    tm, mp = _tile_for(m, tm, xblk[0], xr)
+    tn, np_ = _tile_for(n, tn, wblk[1], wc)
     kblk = max(xblk[1], wblk[0])
     assert kblk % xblk[1] == 0 and kblk % wblk[0] == 0, (xblk, wblk)
-    tk, kp = _tile_for(k, tk, kblk)
+    tk, kp = _tile_for(k, tk, kblk, math.lcm(xc, wr))
     y = mxsf_matmul_pallas(
         _pad2d(x_codes, mp, kp),
-        _pad2d(x_scales, mp // xblk[0], kp // xblk[1]),
+        to_kernel_scales(_pad2d(x_scales, mp // xblk[0], kp // xblk[1]),
+                         xblk),
         _pad2d(w_codes, kp, np_),
-        _pad2d(w_scales, kp // wblk[0], np_ // wblk[1]),
+        to_kernel_scales(_pad2d(w_scales, kp // wblk[0], np_ // wblk[1]),
+                         wblk),
         xblk=tuple(xblk), wblk=tuple(wblk),
-        tm=tm, tn=tn, tk=tk, interpret=_interpret())
+        tm=tm, tn=tn, tk=tk, interpret=interpret)
     return y[:m, :n]
 
 
@@ -133,23 +162,28 @@ def mxsf_fused_matmul(x, w_codes, w_scales, xblk=(1, 32), wblk=(32, 1),
     m, k = x.shape
     kw, n = w_codes.shape
     assert kw >= k and kw % wblk[0] == 0, (k, kw, wblk)
-    tm, mp = _tile_for(m, tm, xblk[0])
-    tn, np_ = _tile_for(n, tn, wblk[1])
+    interpret = _interpret()
+    xr, xc = _align(interpret, *([xblk] if emit_codes else []))
+    wr, wc = _align(interpret, wblk)
+    tm, mp = _tile_for(m, tm, xblk[0], xr)
+    tn, np_ = _tile_for(n, tn, wblk[1], wc)
     kblk = max(xblk[1], wblk[0])
     assert kblk % xblk[1] == 0 and kblk % wblk[0] == 0, (xblk, wblk)
-    tk, kp = _tile_for(kw, tk, kblk)
+    tk, kp = _tile_for(kw, tk, kblk, math.lcm(xc, wr))
     # no host-side upcast: the kernel casts per-tile in VMEM, so bf16
     # activations stream 2 bytes/elem from HBM, not 4
     out = mxsf_fused_matmul_pallas(
         _pad2d(x, mp, kp),
         _pad2d(w_codes, kp, np_),
-        _pad2d(w_scales, kp // wblk[0], np_ // wblk[1]),
+        to_kernel_scales(_pad2d(w_scales, kp // wblk[0], np_ // wblk[1]),
+                         wblk),
         xblk=tuple(xblk), wblk=tuple(wblk), tm=tm, tn=tn, tk=tk,
         quantize_lhs=quantize_lhs, emit_codes=emit_codes,
-        interpret=_interpret())
+        interpret=interpret)
     if not emit_codes:
         return out[:m, :n]
     y, codes, scales = out
+    scales = from_kernel_scales(scales, xblk)
     mb, kb = _ceil_to(m, xblk[0]), _ceil_to(k, xblk[1])
     return (y[:m, :n], codes[:mb, :kb],
             scales[: mb // xblk[0], : kb // xblk[1]])
@@ -165,22 +199,25 @@ def mxsf_attention(q, k_codes, k_scales, v_codes, v_scales, *, causal=True,
     decode to 0.0, padded cache columns sit beyond ``kv_len``, and padded
     query rows are cropped before anyone reads them) and crops the output
     back to (BH, S, dh).  K/V may be in row layout (BKV, L, dh) or cache
-    layout (B, L, kv, dh) — see ``mxsf_flash_attention``.  ``kv_len``/
+    layout (B, KV, L, dh) — see ``mxsf_flash_attention``.  ``kv_len``/
     ``q_offset``/``window`` are dynamic per-row scalars; a growing decode
     cache — or a prefill chunk at any position — reuses one compile.
     """
     BH, S, dh = q.shape
-    L = k_codes.shape[1]
-    cq_, sp = _tile_for(S, cq, 1)
-    ck_, lp = _tile_for(L, ck, 1)
+    L = k_codes.shape[-2]
+    interpret = _interpret()
+    cq_, sp = _tile_for(S, cq, 1, 1 if interpret else 8)
+    # the (KV, Ck) scale block puts Ck on the lanes
+    ck_, lp = _tile_for(L, ck, 1, 1 if interpret else 128)
     if sp > S:
         q = jnp.pad(q, ((0, 0), (0, sp - S), (0, 0)))
     if lp > L:
-        pad = [(0, 0)] * k_codes.ndim
-        pad[1] = (0, lp - L)
-        k_codes = jnp.pad(k_codes, pad)
-        v_codes = jnp.pad(v_codes, pad)
-        spad = pad[: k_scales.ndim]
+        cpad = [(0, 0)] * k_codes.ndim
+        cpad[-2] = (0, lp - L)
+        spad = [(0, 0)] * k_scales.ndim
+        spad[-1] = (0, lp - L)
+        k_codes = jnp.pad(k_codes, cpad)
+        v_codes = jnp.pad(v_codes, cpad)
         k_scales = jnp.pad(k_scales, spad)
         v_scales = jnp.pad(v_scales, spad)
     # resolve negative/None kv_len against the UNPADDED width so the padded
@@ -189,5 +226,5 @@ def mxsf_attention(q, k_codes, k_scales, v_codes, v_scales, *, causal=True,
     y = mxsf_flash_attention(q, k_codes, k_scales, v_codes, v_scales,
                              causal=causal, cq=cq_, ck=ck_, kv_len=kvl,
                              q_offset=q_offset, window=window,
-                             interpret=_interpret())
+                             interpret=interpret)
     return y[:, :S]

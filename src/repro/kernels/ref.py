@@ -65,28 +65,23 @@ def mxsf_flash_attention_ref(q, k_codes, k_scales, v_codes, v_scales,
     scalars (python int, scalar, or (BH,) array); fully-masked rows return 0
     (not a uniform average) — same contract as the kernel's masked-tile fix.
     Accepts both kernel operand layouts: row layout (BKV, L, dh)/(BKV, L)
-    and the KV-cache pytree layout (B, L, kv, dh)/(B, L, kv, 1), adapted
-    here exactly like ``models/decoding.py::kv_cache_rows`` so prefill/
+    and the KV-cache pytree layout (B, KV, L, dh)/(B, KV, L), merged into
+    rows exactly like ``models/decoding.py::kv_cache_rows`` so prefill/
     decode tests can feed the cache buffers straight to the oracle.
     """
     from .mxsf_attention import NO_WINDOW, per_row_scalar
     BH, S, dh = q.shape
     if k_codes.ndim == 4:  # cache layout -> (batch x kv-head) rows
-        Bc, L, KV, _ = k_codes.shape
-
-        def rows(c):
-            return c.transpose(0, 2, 1, 3).reshape(Bc * KV, L, dh)
-
-        def srows(s):
-            return s[..., 0].transpose(0, 2, 1).reshape(Bc * KV, L)
-
-        k_codes, k_scales = rows(k_codes), srows(k_scales)
-        v_codes, v_scales = rows(v_codes), srows(v_scales)
+        Bc, KV, L, _ = k_codes.shape
+        k_codes, v_codes = (c.reshape(Bc * KV, L, dh)
+                            for c in (k_codes, v_codes))
+        k_scales, v_scales = (s.reshape(Bc * KV, L)
+                              for s in (k_scales, v_scales))
     BKV, L, _ = k_codes.shape
     g = BH // BKV
-    kvl = jnp.minimum(per_row_scalar(kv_len, L, BH), L)[:, 0]
-    off = per_row_scalar(q_offset, 0, BH)[:, 0]
-    win = per_row_scalar(window, NO_WINDOW, BH)[:, 0]
+    kvl = jnp.minimum(per_row_scalar(kv_len, L, BH), L)
+    off = per_row_scalar(q_offset, 0, BH)
+    win = per_row_scalar(window, NO_WINDOW, BH)
     k = B.dequantize(B.QuantizedTensor(k_codes, k_scales[..., None], "mxsf",
                                        (dh,), k_codes.shape, "float32"))
     v = B.dequantize(B.QuantizedTensor(v_codes, v_scales[..., None], "mxsf",

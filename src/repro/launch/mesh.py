@@ -211,15 +211,16 @@ def cache_shardings(rules: MeshRules, cache_shapes, batch_size: int):
         shape = leaf.shape
         keys = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
         name = keys[-1] if keys else ""
-        if name in ("k_codes", "v_codes"):
-            name = "k"  # packed cache codes shard like the kv tensor
-        if name in ("k_scales", "v_scales"):
-            name = "k"  # (..., B, W, kv, 1): same rule, last dim size 1
+        # packed cache codes shard like the kv tensor; the scales are the
+        # same (..., B, kv, W) without the trailing dh
+        lead = len(shape) - (3 if name in ("k_scales", "v_scales") else 4)
+        if name in ("k_codes", "v_codes", "k_scales", "v_scales"):
+            name = "k"
         spec = [None] * len(shape)
         if name in ("k", "v"):
-            # (..., B, W, kv, dh) — mirrors the _attend TP rule:
+            # (..., B, kv, W, dh) — mirrors the _attend TP rule:
             # kv heads over TP when divisible, else cache length over TP
-            b_ax, w_ax, kv_ax = len(shape) - 4, len(shape) - 3, len(shape) - 2
+            b_ax, kv_ax, w_ax = lead, lead + 1, lead + 2
             w_axes = []
             if batch_size % rules.dp_size == 0 and rules.dp:
                 spec[b_ax] = rules.dp

@@ -21,6 +21,7 @@ from ..core.policy import QuantPolicy
 from ..data.pipeline import lm_batch, vision_batch
 from ..optim.adamw import OptConfig
 from ..runtime import fault
+from ..runtime.compile_cache import use_compile_cache
 from ..train import step as T
 
 
@@ -50,6 +51,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     policy = build_policy(args.policy, args.block_mode)

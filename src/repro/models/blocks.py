@@ -26,15 +26,16 @@ def _dense_init(key, d_in, d_out, scale=None):
     return jax.random.normal(key, (d_in, d_out), jnp.float32) * scale
 
 
-def dense(x, w, policy):
+def dense(x, w, policy, tp_in: bool = False):
     """mx_dot with cast-at-use: f32 master weights -> activation dtype.
 
     ``w`` may also be a resident packed weight (``QuantizedTensor``) from
     the pack-once store — those were cast to the compute dtype at pack time
     and mx_dot consumes the codes directly (zero weight-quantize
-    dispatches)."""
+    dispatches).  ``tp_in`` marks the row-parallel weights (wo, wd,
+    out_proj; see ``mx_dot``)."""
     if isinstance(w, QuantizedTensor):
-        return mx_dot(x, w, policy)
+        return mx_dot(x, w, policy, tp_in)
     return mx_dot(x, w.astype(x.dtype), policy)
 
 
@@ -159,9 +160,9 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
     if "bq" in p:
         q = (q + p["bq"]).astype(x.dtype)
     q = _split_heads(q, h, dh)
-    if kv_cached is not None:
-        k = kv_cached["k"].astype(x.dtype)
-        v = kv_cached["v"].astype(x.dtype)
+    if kv_cached is not None:  # (B, kv, L, dh) cache layout
+        k = kv_cached["k"].astype(x.dtype).transpose(0, 2, 1, 3)
+        v = kv_cached["v"].astype(x.dtype).transpose(0, 2, 1, 3)
         kpos = jnp.zeros((B, k.shape[1]), jnp.int32)
         return _attend(q, k, v, None, kpos, False, None,
                        p, x, cfg, policy), None
@@ -192,14 +193,16 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
         # cache_pos may be a scalar (lockstep batch) or a (B,) vector of
         # per-sequence positions (continuous batching, serve/engine.py)
         pos_vec = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (B,))
-        W = (cache["k_codes"] if "k_codes" in cache else cache["k"]).shape[1]
+        # per-slot buffers are (kv, W, dh) codes/values and (kv, W) scales:
+        # positions on axis 1 of every leaf
+        W = (cache["k_codes"] if "k_codes" in cache else cache["k"]).shape[2]
         slot = pos_vec % W
 
         if cache_write_len is None:
             def _write(buf, upd):
                 return jax.vmap(
-                    lambda c, u, p: jax.lax.dynamic_update_slice(c, u,
-                                                                 (p, 0, 0))
+                    lambda c, u, p: jax.lax.dynamic_update_slice(
+                        c, u, (0, p) + (0,) * (c.ndim - 2))
                 )(buf, upd, slot)
         else:
             # masked chunk write (prefill): scatter rows 0..len-1 onto
@@ -216,7 +219,7 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
 
             def _write(buf, upd):
                 return jax.vmap(
-                    lambda c, u, cc: c.at[cc].set(u, mode="drop")
+                    lambda c, u, cc: c.at[:, cc].set(u, mode="drop")
                 )(buf, upd, cols)
 
         # last absolute position actually WRITTEN this call: all S rows on
@@ -241,10 +244,10 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
         fmt = policy.kv_cache_fmt or "mxsf"
         new_cache = dict(cache)
         for nm, val in (("k", k), ("v", v)):
-            qt = mxblk.quantize(val, fmt, (dh,))
+            qt = mxblk.quantize(val.transpose(0, 2, 1, 3), fmt, (dh,))
             new_cache[f"{nm}_codes"] = _write(cache[f"{nm}_codes"], qt.codes)
             new_cache[f"{nm}_scales"] = _write(cache[f"{nm}_scales"],
-                                               qt.scale_e8m0)
+                                               qt.scale_e8m0[..., 0])
         if attn_kernel_eligible(cfg, policy) and kv_x is None and causal:
             # cached causal self-attention through the flash kernel — S=1
             # decode steps AND S=C prefill chunks: it reads the 1-byte codes
@@ -255,18 +258,19 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
             # chunk (kpos <= qpos < pos + write_len).
             return _attend_packed(q, new_cache, pos_vec, window, p, cfg,
                                   policy), new_cache
-        kc, vc = new_cache["k_codes"], new_cache["v_codes"]
-        k = mxblk.dequantize(mxblk.QuantizedTensor(
-            kc, new_cache["k_scales"], fmt, (dh,), kc.shape, str(x.dtype)))
-        v = mxblk.dequantize(mxblk.QuantizedTensor(
-            vc, new_cache["v_scales"], fmt, (dh,), vc.shape, str(x.dtype)))
+        k, v = (mxblk.dequantize(mxblk.QuantizedTensor(
+            new_cache[f"{nm}_codes"], new_cache[f"{nm}_scales"][..., None],
+            fmt, (dh,), new_cache[f"{nm}_codes"].shape, str(x.dtype)
+        )).transpose(0, 2, 1, 3) for nm in ("k", "v"))
     elif cache is not None:
-        # ring buffer (B, W, kv, dh); contiguous non-wrapping writes only
+        # ring buffer (B, kv, W, dh); contiguous non-wrapping writes only
         # (decode S=1 anywhere; prefill S>1 requires cache_pos=0, W >= S).
-        ck = _write(cache["k"], k.astype(cache["k"].dtype))
-        cv = _write(cache["v"], v.astype(cache["v"].dtype))
+        ck = _write(cache["k"], k.transpose(0, 2, 1, 3).astype(
+            cache["k"].dtype))
+        cv = _write(cache["v"], v.transpose(0, 2, 1, 3).astype(
+            cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
-        k, v = ck, cv
+        k, v = ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3)
     else:
         qpos = jnp.broadcast_to(positions if positions.ndim == 2
                                 else positions[None, :], (B, S))
@@ -299,29 +303,46 @@ def _attend_packed(q, cache, pos_vec, window, p, cfg: ModelConfig,
     """
     from ..kernels import ops as kops
     B, S, h, dh = q.shape
+    kv = cfg.n_kv
     # cache-layout operands go to the kernel as-is — the BlockSpec index
-    # maps adapt (B, W, kv, dh) to kernel rows, so the packed cache never
-    # makes a relaid HBM copy (see decoding.kv_cache_rows for the mapping)
-    kc, ks = cache["k_codes"], cache["k_scales"]
-    vc, vs = cache["v_codes"], cache["v_scales"]
-    # under a mesh, pin q to the cache's layout (batch over DP, heads over
-    # TP) so the kernel's (batch x head) rows sit with their kv rows and
-    # GSPMD partitions the grid instead of gathering the cache
+    # maps read (B, kv, W, dh) per (batch x head) row, so the packed cache
+    # never makes a relaid HBM copy (see decoding.kv_cache_rows)
+    caches = (cache["k_codes"], cache["k_scales"], cache["v_codes"],
+              cache["v_scales"])
     q = shd.constrain(q, "batch", None, "heads", None)
-    qr = q.transpose(0, 2, 1, 3).reshape(B * h, S, dh)
+    qh = q.transpose(0, 2, 1, 3)                            # (B, h, S, dh)
     if policy.attn_matmuls:
-        qr = qdq_along(qr, policy.fwd_fmt, policy, -1)
-    kvl = jnp.repeat(pos_vec + S, h)   # slots 0..pos hold positions 0..pos
-    off = jnp.repeat(pos_vec, h)       # the query sits at absolute pos
-    win = (None if window is None else
-           jnp.repeat(jnp.broadcast_to(jnp.asarray(window, jnp.int32), (B,)),
-                      h))
-    y = kops.mxsf_attention(qr, kc, ks, vc, vs, causal=True, kv_len=kvl,
-                            q_offset=off, window=win)
-    ctx = y.reshape(B, h, S, dh).transpose(0, 2, 1, 3).reshape(B, S, h * dh)
+        qh = qdq_along(qh, policy.fwd_fmt, policy, -1)
+    per_head = lambda v: jnp.broadcast_to(
+        jnp.asarray(v, jnp.int32).reshape(-1, 1), (B, h))
+    scalars = [per_head(pos_vec + S),   # slots 0..pos hold positions 0..pos
+               per_head(pos_vec)]       # the query sits at absolute pos
+    if window is not None:
+        scalars.append(per_head(jnp.broadcast_to(window, (B,))))
+
+    def local(qh, kc, ks, vc, vs, kvl, off, *win):
+        b, hl = qh.shape[:2]
+        y = kops.mxsf_attention(
+            qh.reshape(b * hl, S, dh), kc, ks, vc, vs, causal=True,
+            kv_len=kvl.reshape(-1), q_offset=off.reshape(-1),
+            window=win[0].reshape(-1) if win else None)
+        return y.reshape(b, hl, S, dh)
+
+    # under a mesh the kernel runs shard-local: slots over DP with their
+    # cache rows, heads over TP with their kv heads (whole GQA groups; the
+    # engine routes a cache whose kv heads do not split to the jnp path)
+    P = jax.sharding.PartitionSpec
+    bs = shd.split_axes(B, "batch")
+    ts = shd.split_axes(kv, "kv")
+    y = shd.shard_local(
+        local,
+        (P(bs, ts, None, None),) + (P(bs, ts, None, None), P(bs, ts, None)) * 2
+        + (P(bs, ts),) * len(scalars),
+        P(bs, ts, None, None))(qh, *caches, *scalars)
+    ctx = y.transpose(0, 2, 1, 3).reshape(B, S, h * dh)
     # 'hidden' puts the flattened head dim on TP, matching wo's row shard
     ctx = shd.constrain(ctx, "batch", None, "hidden")
-    return dense(ctx, p["wo"], policy)
+    return dense(ctx, p["wo"], policy, tp_in=True)
 
 
 ATTN_CHUNK = 1024  # query-chunk target (flash-style; bounds score memory)
@@ -395,7 +416,7 @@ def _attend(q, k, v, qpos, kpos, causal, window, p, x, cfg: ModelConfig,
         # (n, B, kv, g, chunk, dh) -> (B, kv, g, S, dh)
         ctx = ctx.transpose(1, 2, 3, 0, 4, 5).reshape(B, kv, g, S, dh)
     ctx = ctx.transpose(0, 3, 1, 2, 4).reshape(B, S, h * dh)
-    return dense(ctx, p["wo"], policy)
+    return dense(ctx, p["wo"], policy, tp_in=True)
 
 
 # ---------------------------------------------------------------------------
@@ -417,9 +438,9 @@ def mlp(p, x, cfg: ModelConfig, policy: QuantPolicy):
             (lambda v: jax.nn.gelu(v, approximate=True))
         gate = act(dense(x, p["wg"], policy))
         up = dense(x, p["wu"], policy)
-        return dense(gate * up, p["wd"], policy)
+        return dense(gate * up, p["wd"], policy, tp_in=True)
     h = jax.nn.gelu(dense(x, p["wu"], policy), approximate=True)
-    return dense(h, p["wd"], policy)
+    return dense(h, p["wd"], policy, tp_in=True)
 
 
 # ---------------------------------------------------------------------------

@@ -7,12 +7,12 @@ masked cache writes (continuous batching, serve/engine.py), and
 ``decode_step`` runs one token.
 
 Cache layouts (stacked over layers for ``lax.scan``):
-  * decoder : k/v ring buffers (n_super, moe_every, B, W, kv, dh); W is the
+  * decoder : k/v ring buffers (n_super, moe_every, B, kv, W, dh); W is the
     SWA window when the arch is all-SWA (danube long-context: W=4096 ring)
     else the full max_len.
   * ssm     : recurrent state + conv tail, (L, ...).
   * hybrid  : ssm caches grouped (G, per, ...) (+tail) + one attention cache
-    per shared-block application (G, B, W, kv, dh).
+    per shared-block application (G, B, kv, W, dh).
   * encdec  : decoder self-attn cache + precomputed cross-attn k/v.
 """
 from __future__ import annotations
@@ -35,37 +35,26 @@ __all__ = ["init_cache", "decode_step", "prefill_step", "prefill",
 def kv_cache_rows(cache):
     """One layer's packed KV cache in the flash-kernel *row* layout.
 
-    The cache pytree stores per-layer codes as ``(B, W, kv, dh)`` uint8 with
-    ``(B, W, kv, 1)`` E8M0 scales (position-major, so decode writes are one
-    ``dynamic_update_slice`` per step).  ``kernels/mxsf_attention.py`` maps
-    one kernel row per (batch x kv-head): codes ``(B*kv, W, dh)``, scales
-    ``(B*kv, W)`` — rows batch-major so q row ``b*h + head`` reads kv row
-    ``(b*h + head) // (h // kv) = b*kv + head_kv``.
-
-    The decode hot path does NOT call this: the kernel's cache-layout
-    BlockSpec index maps perform the same adaptation in-place (no relaid
-    HBM copy).  This helper materializes the equivalent row tensors for
-    tests and offline tools; ``tests/test_attention_backend.py`` asserts
-    both layouts produce identical kernel output.
+    The cache pytree stores per-layer codes as ``(B, kv, W, dh)`` uint8 with
+    ``(B, kv, W)`` E8M0 scales: positions on the sublanes of the codes and
+    on the lanes of the scales, the (8, 128)-tiled blocks the TPU kernel
+    reads as-is.  The row layout merges (B, kv) into one ``B*kv`` axis —
+    rows batch-major, so q row ``b*h + head`` reads kv row
+    ``b*kv + head // (h // kv)``.  ``tests/test_attention_backend.py``
+    asserts both layouts produce identical kernel output.
     Returns ``(k_codes, k_scales, v_codes, v_scales)``.
     """
-    kc = cache["k_codes"]
-    B, W, kv, dh = kc.shape
-
-    def rows(c):
-        return c.transpose(0, 2, 1, 3).reshape(B * kv, W, dh)
-
-    def srows(s):
-        return s[..., 0].transpose(0, 2, 1).reshape(B * kv, W)
-
-    return (rows(kc), srows(cache["k_scales"]),
-            rows(cache["v_codes"]), srows(cache["v_scales"]))
+    B, kv, W, dh = cache["k_codes"].shape
+    return (cache["k_codes"].reshape(B * kv, W, dh),
+            cache["k_scales"].reshape(B * kv, W),
+            cache["v_codes"].reshape(B * kv, W, dh),
+            cache["v_scales"].reshape(B * kv, W))
 
 
 def _attn_cache(cfg: ModelConfig, lead, batch, W, dtype, kv_fmt: str = ""):
-    shape = (*lead, batch, W, cfg.n_kv, cfg.head_dim)
+    shape = (*lead, batch, cfg.n_kv, W, cfg.head_dim)
     if kv_fmt:  # 8-bit MX-packed cache: 1B codes + 1B E8M0 scale per head row
-        sshape = (*lead, batch, W, cfg.n_kv, 1)
+        sshape = (*lead, batch, cfg.n_kv, W)
         return {"k_codes": jnp.zeros(shape, jnp.uint8),
                 "k_scales": jnp.zeros(sshape, jnp.uint8),
                 "v_codes": jnp.zeros(shape, jnp.uint8),
@@ -410,8 +399,8 @@ def prefill(params, batch, cache, cfg: ModelConfig, policy: QuantPolicy):
             k = enc @ lp["cross"]["wk"].astype(enc.dtype)
             v = enc @ lp["cross"]["wv"].astype(enc.dtype)
             B, S, _ = k.shape
-            k = k.reshape(B, S, cfg.n_kv, cfg.head_dim)
-            v = v.reshape(B, S, cfg.n_kv, cfg.head_dim)
+            k = k.reshape(B, S, cfg.n_kv, cfg.head_dim).transpose(0, 2, 1, 3)
+            v = v.reshape(B, S, cfg.n_kv, cfg.head_dim).transpose(0, 2, 1, 3)
             return None, {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
 
         _, cross = jax.lax.scan(kv_body, None, params["dec_layers"])

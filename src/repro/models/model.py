@@ -101,9 +101,9 @@ def decode_attn_backend(cfg: ModelConfig, policy: QuantPolicy,
 
 def cache_position_axis_sharded(cache_shardings) -> bool:
     """True when any KV-cache leaf shards its position/window axis (the
-    ``W`` of ``(..., B, W, kv, dh)``) — the one cache layout the packed
-    flash-attention kernel cannot consume shard-local (see
-    ``decode_attn_backend``)."""
+    ``W`` of ``(..., B, kv, W, dh)`` / ``(..., B, kv, W)``) — the one cache
+    layout the packed flash-attention kernel cannot consume shard-local
+    (see ``decode_attn_backend``)."""
     flat = jax.tree_util.tree_flatten_with_path(cache_shardings)[0]
     for path, ns in flat:
         name = str(getattr(path[-1], "key", path[-1])) if path else ""
@@ -111,8 +111,8 @@ def cache_position_axis_sharded(cache_shardings) -> bool:
                         "k_scales", "v_scales"):
             continue
         spec = tuple(ns.spec)
-        w_ax = len(spec) - 3
-        if w_ax >= 0 and spec[w_ax] is not None:
+        w_ax = -1 if name in ("k_scales", "v_scales") else -2
+        if len(spec) >= -w_ax and spec[w_ax] is not None:
             return True
     return False
 

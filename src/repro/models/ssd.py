@@ -66,7 +66,7 @@ def _gate_out(p, y, z, x_resid, cfg, policy):
     B, L = y.shape[:2]
     y = y.reshape(B, L, cfg.d_inner)
     y = rmsnorm({"w": p["norm_w"]}, y * jax.nn.silu(z))
-    return dense(y, p["out_proj"], policy)
+    return dense(y, p["out_proj"], policy, tp_in=True)
 
 
 def ssd_forward(p, u, cfg: ModelConfig, policy: QuantPolicy, *,

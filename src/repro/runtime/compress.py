@@ -21,7 +21,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import blocking as B
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["compressed_psum", "make_compressed_allreduce", "wire_bytes"]
 
@@ -54,7 +53,7 @@ def make_compressed_allreduce(mesh, axis: str = "data", fmt: str = "mxsf",
     def _reduce_leaf(g):
         n = mesh.shape[axis]
 
-        @partial(_shard_map, mesh=mesh, in_specs=P(axis),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(axis),
                  out_specs=P(axis))
         def _psum_shards(gs):
             return compressed_psum(gs, axis, fmt, block) / n

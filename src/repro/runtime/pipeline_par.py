@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import pvary as _pvary
-from ._compat import shard_map as _shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -41,7 +39,7 @@ def pipeline_apply(mesh, stage_axis: str, layer_fn: Callable,
     S = mesh.shape[stage_axis]
     M = x_microbatches.shape[0]
 
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(stage_axis), P()),
              out_specs=P(stage_axis))
     def run(params_stage, xs):
@@ -68,8 +66,8 @@ def pipeline_apply(mesh, stage_axis: str, layer_fn: Callable,
             return (buf_next, outs), None
 
         # carries become device-varying after the first ppermute
-        buf0 = _pvary(jnp.zeros_like(xs[0]), stage_axis)
-        outs0 = _pvary(jnp.zeros_like(xs), stage_axis)
+        buf0 = jax.lax.pvary(jnp.zeros_like(xs[0]), stage_axis)
+        outs0 = jax.lax.pvary(jnp.zeros_like(xs), stage_axis)
         (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(n_ticks))
         return outs
 

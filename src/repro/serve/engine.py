@@ -39,11 +39,12 @@ Generation stops at ``max_new`` tokens, a full cache, or the request's
 (packed-layout ``MeshRules``: codes + shared-exponent scales split
 together, uneven dims replicate), shards the packed KV cache slot-batch
 over the DP axes and kv-heads over the TP axis, and jits both entry
-points with explicit in/out shardings under ``sharding.mesh_context`` so
-the role constraints in ``models/blocks.py`` resolve to mesh axes.  GSPMD
-partitioning is semantics-preserving, so a sharded engine is
-token-for-token identical to the single-device one (asserted across mesh
-shapes in tests/test_sharded_serving.py).  Kernel gates are re-checked
+points with explicit in/out shardings, traced under
+``sharding.mesh_context`` so the role constraints in ``models/blocks.py``
+resolve to mesh axes and the Pallas kernels run shard-local
+(``sharding.shard_local``).  A sharded engine is token-for-token
+identical to the single-device one (asserted across mesh shapes in
+tests/test_sharded_serving.py).  Kernel gates are re-checked
 per shard: a layout the flash-attention kernel cannot consume shard-local
 falls back to the jnp path for this engine only, recorded in
 ``shard_fallback``.  ``stats()`` reports dispatch counts, occupancy and
@@ -59,7 +60,6 @@ tests/test_chunked_prefill.py.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import os
@@ -258,9 +258,11 @@ class ServeEngine:
         # shardings (store + cache stay put, token/position/logit batches
         # split over DP) and are traced inside sharding.mesh_context so the
         # role constraints in models/blocks.py resolve to mesh axes
-        step = lambda p, t, c, pos: M.decode_step(p, t, c, pos, cfg, policy)
-        pre = lambda p, t, c, pos, nv: M.prefill_step(p, t, c, pos, nv,
-                                                      cfg, policy)
+        step = self._traced(
+            lambda p, t, c, pos: M.decode_step(p, t, c, pos, cfg, policy))
+        pre = self._traced(
+            lambda p, t, c, pos, nv: M.prefill_step(p, t, c, pos, nv, cfg,
+                                                    policy))
         if self.rules is None:
             self._decode = jax.jit(step)
             self._prefill = jax.jit(pre) if chunk > 1 else None
@@ -305,13 +307,20 @@ class ServeEngine:
                 return False
         return True
 
-    def _hints(self):
-        """Role-constraint context for dispatches: under a mesh, activates
-        the ``sharding.constrain`` hints in models/blocks.py (trace-time),
-        else a no-op."""
+    def _traced(self, fn):
+        """``fn`` traced inside ``sharding.mesh_context`` under a mesh, so
+        the role hints in models/blocks.py resolve to mesh axes and the
+        Pallas kernels run shard-local however the step is lowered
+        (dispatch or ahead of time); ``fn`` itself without a mesh."""
         if self.rules is None:
-            return contextlib.nullcontext()
-        return shd.mesh_context(self.mesh, self.rules.dp, self.rules.tp)
+            return fn
+        mesh, dp, tp = self.mesh, self.rules.dp, self.rules.tp
+
+        def traced(*args):
+            with shd.mesh_context(mesh, dp, tp):
+                return fn(*args)
+
+        return traced
 
     @classmethod
     def from_checkpoint(cls, cfg: ModelConfig, ckpt_dir: str,
@@ -447,11 +456,10 @@ class ServeEngine:
         # its position — which the prefill dispatch below then overwrites
         # with the chunk's first real token before anything attends to it.
         if decode_slots:
-            with self._hints():
-                logits, self.cache = self._decode(
-                    self.params,
-                    jnp.asarray(self.last_tok)[:, None].astype(jnp.int32),
-                    self.cache, jnp.asarray(self.pos))
+            logits, self.cache = self._decode(
+                self.params,
+                jnp.asarray(self.last_tok)[:, None].astype(jnp.int32),
+                self.cache, jnp.asarray(self.pos))
             self.decode_dispatches += 1
             nxt = np.asarray(self.sampler(logits))
             for s in decode_slots:
@@ -472,10 +480,9 @@ class ServeEngine:
                 for j in range(n):
                     toks[s, j] = q.popleft()
                 nv[s] = n
-            with self._hints():
-                logits, self.cache = self._prefill(
-                    self.params, jnp.asarray(toks), self.cache,
-                    jnp.asarray(self.pos), jnp.asarray(nv))
+            logits, self.cache = self._prefill(
+                self.params, jnp.asarray(toks), self.cache,
+                jnp.asarray(self.pos), jnp.asarray(nv))
             self.prefill_dispatches += 1
             nxt = np.asarray(self.sampler(logits))
             for s in prefill_slots:
@@ -496,10 +503,9 @@ class ServeEngine:
             if self.live[s] is not None and self.pending_prompt[s]:
                 toks[s] = self.pending_prompt[s].popleft()
                 prefilling[s] = True
-        with self._hints():
-            logits, self.cache = self._decode(
-                self.params, jnp.asarray(toks)[:, None].astype(jnp.int32),
-                self.cache, jnp.asarray(self.pos))
+        logits, self.cache = self._decode(
+            self.params, jnp.asarray(toks)[:, None].astype(jnp.int32),
+            self.cache, jnp.asarray(self.pos))
         # a tick that consumed any prompt token is a prefill dispatch (the
         # token-by-token path merges both phases into one dispatch)
         if prefilling.any():

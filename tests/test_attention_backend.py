@@ -111,12 +111,12 @@ def test_cache_layout_matches_row_layout():
 
     Bsz, W, kv, dh, h = 2, 24, 2, 16, 4
     rng = np.random.default_rng(13)
-    kvals = rng.standard_normal((2, Bsz, W, kv, dh)).astype(np.float32)
+    kvals = rng.standard_normal((2, Bsz, kv, W, dh)).astype(np.float32)
     cache = {}
     for nm, val in (("k", kvals[0]), ("v", kvals[1])):
         qt = B.quantize(jnp.asarray(val), "mxsf", (dh,))
         cache[f"{nm}_codes"] = qt.codes
-        cache[f"{nm}_scales"] = qt.scale_e8m0
+        cache[f"{nm}_scales"] = qt.scale_e8m0[..., 0]
     q = jnp.asarray(rng.standard_normal((Bsz * h, 1, dh)).astype(np.float32))
     kvl = jnp.asarray(rng.integers(1, W + 1, size=Bsz * h), jnp.int32)
     off = kvl - 1

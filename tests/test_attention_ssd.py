@@ -51,7 +51,7 @@ def test_ring_cache_decode_matches_full_cache():
 
     ring = M.init_cache(cfg, B, steps, dtype=jnp.float32, ring=True)
     full = M.init_cache(cfg, B, steps, dtype=jnp.float32, ring=False)
-    assert ring["k"].shape[-3] == 8 and full["k"].shape[-3] == steps
+    assert ring["k"].shape[-2] == 8 and full["k"].shape[-2] == steps
     for t in range(steps):
         lr, ring = M.decode_step(params, toks[:, t:t + 1], ring,
                                  jnp.int32(t), cfg, BF16)

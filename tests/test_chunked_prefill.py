@@ -206,11 +206,12 @@ def test_prefill_chunk_attention_kernel_vs_oracle():
 
     rng = np.random.default_rng(7)
     Bsz, W, kv, dh, h, S = 2, 24, 2, 16, 4, 5
-    kvals = rng.standard_normal((2, Bsz, W, kv, dh)).astype(np.float32)
+    kvals = rng.standard_normal((2, Bsz, kv, W, dh)).astype(np.float32)
     cache = {}
     for nm, val in (("k", kvals[0]), ("v", kvals[1])):
         qt = B.quantize(jnp.asarray(val), "mxsf", (dh,))
-        cache[f"{nm}_codes"], cache[f"{nm}_scales"] = qt.codes, qt.scale_e8m0
+        cache[f"{nm}_codes"] = qt.codes
+        cache[f"{nm}_scales"] = qt.scale_e8m0[..., 0]
     q = jnp.asarray(rng.standard_normal((Bsz * h, S, dh)).astype(np.float32))
     # chunk starts at position 3 with 3+S valid keys — decode-style dynamics
     off = jnp.full((Bsz * h,), 3, jnp.int32)
