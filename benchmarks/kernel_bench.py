@@ -269,10 +269,9 @@ def run():
          dispatches=d_chk, hbm_bytes=hbm_chk)
 
     # prefill_chunk="auto" resolution (serve/engine.auto_prefill_chunk):
-    # what the engine picks when no explicit C is given — shape heuristic
-    # (fill one fused-matmul M tile across the slot batch, drain a full
-    # prompt in >= 4 chunks) floored by the chunked-prefill C measured
-    # above, so the bench rows feed the tuner they were built for
+    # what the engine picks when no explicit C is given — a shape
+    # heuristic (fill one fused-matmul M tile across the slot batch, drain
+    # a full prompt in >= 4 chunks)
     from repro.serve.engine import auto_prefill_chunk
     for ml, sl in ((256, 4), (4096, 16)):
         ac = auto_prefill_chunk(ml, sl)

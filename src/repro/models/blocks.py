@@ -164,8 +164,9 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
         k = kv_cached["k"].astype(x.dtype).transpose(0, 2, 1, 3)
         v = kv_cached["v"].astype(x.dtype).transpose(0, 2, 1, 3)
         kpos = jnp.zeros((B, k.shape[1]), jnp.int32)
-        return _attend(q, k, v, None, kpos, False, None,
-                       p, x, cfg, policy), None
+        with jax.named_scope("attention"):
+            ctx = _attend(q, k, v, None, kpos, False, None, x, cfg, policy)
+        return dense(ctx, p["wo"], policy, tp_in=True), None
     src = x if kv_x is None else kv_x
     k = dense(src, p["wk"], policy)
     v = dense(src, p["wv"], policy)
@@ -256,12 +257,15 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
             # qpos/kpos comparison (q_offset = pos_vec), which also keeps
             # valid queries off any unwritten tail columns of a partial
             # chunk (kpos <= qpos < pos + write_len).
-            return _attend_packed(q, new_cache, pos_vec, window, p, cfg,
-                                  policy), new_cache
-        k, v = (mxblk.dequantize(mxblk.QuantizedTensor(
-            new_cache[f"{nm}_codes"], new_cache[f"{nm}_scales"][..., None],
-            fmt, (dh,), new_cache[f"{nm}_codes"].shape, str(x.dtype)
-        )).transpose(0, 2, 1, 3) for nm in ("k", "v"))
+            with jax.named_scope("attention"):
+                ctx = _attend_packed(q, new_cache, pos_vec, window, cfg,
+                                     policy)
+            return dense(ctx, p["wo"], policy, tp_in=True), new_cache
+        with jax.named_scope("attention"):
+            k, v = (mxblk.dequantize(mxblk.QuantizedTensor(
+                new_cache[f"{nm}_codes"], new_cache[f"{nm}_scales"][..., None],
+                fmt, (dh,), new_cache[f"{nm}_codes"].shape, str(x.dtype)
+            )).transpose(0, 2, 1, 3) for nm in ("k", "v"))
     elif cache is not None:
         # ring buffer (B, kv, W, dh); contiguous non-wrapping writes only
         # (decode S=1 anywhere; prefill S>1 requires cache_pos=0, W >= S).
@@ -280,16 +284,21 @@ def attention(p, x, cfg: ModelConfig, policy: QuantPolicy, *,
             kpos = jnp.zeros((B, k.shape[1]), jnp.int32)
             qpos = None
 
-    return _attend(q, k, v, qpos, kpos, causal and kv_x is None, window,
-                   p, x, cfg, policy,
-                   kv_prequant=bool(cache is not None
-                                    and "k_codes" in cache)), new_cache
+    # the "attention" scope names what lies between the projections,
+    # whichever path computes it: the cache read, scores, softmax and PV
+    with jax.named_scope("attention"):
+        ctx = _attend(q, k, v, qpos, kpos, causal and kv_x is None, window,
+                      x, cfg, policy,
+                      kv_prequant=bool(cache is not None
+                                       and "k_codes" in cache))
+    return dense(ctx, p["wo"], policy, tp_in=True), new_cache
 
 
-def _attend_packed(q, cache, pos_vec, window, p, cfg: ModelConfig,
+def _attend_packed(q, cache, pos_vec, window, cfg: ModelConfig,
                    policy: QuantPolicy):
     """Cached attention consuming the packed MXSF cache directly — S=1
     decode steps and S=C prefill chunks (the q-side grid tiles over S).
+    Returns the (B, S, h * dh) context that ``wo`` projects.
 
     Routes through ``kernels/ops.py::mxsf_attention`` (SAFE-MAC dataflow:
     E8M0-scaled codes decoded at the MAC array).  q is 1D-quantized along dh
@@ -341,8 +350,7 @@ def _attend_packed(q, cache, pos_vec, window, p, cfg: ModelConfig,
         P(bs, ts, None, None))(qh, *caches, *scalars)
     ctx = y.transpose(0, 2, 1, 3).reshape(B, S, h * dh)
     # 'hidden' puts the flattened head dim on TP, matching wo's row shard
-    ctx = shd.constrain(ctx, "batch", None, "hidden")
-    return dense(ctx, p["wo"], policy, tp_in=True)
+    return shd.constrain(ctx, "batch", None, "hidden")
 
 
 ATTN_CHUNK = 1024  # query-chunk target (flash-style; bounds score memory)
@@ -375,10 +383,11 @@ def _scores_block(qg_c, kk, vv, qpos_c, kpos, causal, window, dh, cfg,
     return shd.constrain(ctx, "batch", "kv", None, None, None)
 
 
-def _attend(q, k, v, qpos, kpos, causal, window, p, x, cfg: ModelConfig,
+def _attend(q, k, v, qpos, kpos, causal, window, x, cfg: ModelConfig,
             policy: QuantPolicy, kv_prequant: bool = False):
     """Query-chunked attention: the full (S x L) score tensor never
-    materializes (peak is one (C x L) block per device).
+    materializes (peak is one (C x L) block per device).  Returns the
+    (B, S, h * dh) context that ``wo`` projects.
 
     TP assignment (core/sharding.py): the kv-head dim when it divides the
     TP axis, else the key/cache length (sequence parallelism) — the same
@@ -415,8 +424,7 @@ def _attend(q, k, v, qpos, kpos, causal, window, p, x, cfg: ModelConfig,
         _, ctx = jax.lax.scan(body, None, (qg_c, qpos_c))
         # (n, B, kv, g, chunk, dh) -> (B, kv, g, S, dh)
         ctx = ctx.transpose(1, 2, 3, 0, 4, 5).reshape(B, kv, g, S, dh)
-    ctx = ctx.transpose(0, 3, 1, 2, 4).reshape(B, S, h * dh)
-    return dense(ctx, p["wo"], policy, tp_in=True)
+    return ctx.transpose(0, 3, 1, 2, 4).reshape(B, S, h * dh)
 
 
 # ---------------------------------------------------------------------------

@@ -82,21 +82,24 @@ def test_make_test_mesh_clamps_both_axes():
     assert dict(m.shape) == {"data": 1, "model": 1}
 
 
-def test_auto_prefill_chunk_heuristic(tmp_path):
+def test_auto_prefill_chunk_heuristic(tmp_path, monkeypatch):
     # bounded by the cache width and >= 1 everywhere
     for ml, sl in ((1, 1), (8, 2), (256, 4), (4096, 16), (16, 64)):
-        c = auto_prefill_chunk(ml, sl, bench_path=str(tmp_path / "none"))
+        c = auto_prefill_chunk(ml, sl)
         assert 1 <= c <= ml, (ml, sl, c)
     # the shape heuristic: fill one fused-matmul M tile across slots,
     # drain a full prompt in >= 4 chunks
-    assert auto_prefill_chunk(256, 4, bench_path=str(tmp_path / "n")) == 64
-    assert auto_prefill_chunk(16, 2, bench_path=str(tmp_path / "n")) == 4
-    # measured bench rows floor the pick
+    assert auto_prefill_chunk(256, 4) == 64
+    assert auto_prefill_chunk(16, 2) == 4
+    # a kernel-bench file in the working directory (or named by the
+    # variable the bench writes to) does not change the pick
     bench = tmp_path / "BENCH_kernel.json"
     bench.write_text(json.dumps({"rows": [
         {"name": "kernel_prefill_chunked_dispatches", "derived": "P=12,C=8"},
     ]}))
-    assert auto_prefill_chunk(16, 64, bench_path=str(bench)) == 8
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("BENCH_KERNEL_JSON", str(bench))
+    assert auto_prefill_chunk(16, 64) == 4
     # integer values keep exact current behavior (no heuristic involved)
     cfg = _cfg()
     params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -120,6 +123,13 @@ def test_engine_stats_accounting():
     assert st["prefill_dispatches"] == eng.prefill_dispatches > 0
     assert st["decode_dispatches"] == eng.decode_dispatches > 0
     assert st["ticks"] == eng.ticks > 0
+    # rows handed to the steps and rows that did work: every prompt token
+    # and every decode row (the first token comes from the prefill)
+    assert st["rows_computed"] == eng.slots * (
+        st["decode_dispatches"] + eng.prefill_chunk
+        * st["prefill_dispatches"])
+    assert st["rows_useful"] == sum(len(p) for p in _prompts(cfg)) + sum(
+        len(t) - 1 for t in toks)
     assert 0.0 < st["occupancy"] <= 1.0
     assert st["mesh"] is None and st["shard_fallback"] is None
     assert st["live"] == 0 and st["queued"] == 0
