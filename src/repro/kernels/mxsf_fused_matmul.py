@@ -16,13 +16,22 @@ matmul.  This kernel folds the MXSF Converter into the matmul prologue:
 Quantize->decode through the byte codec (not a value-domain shortcut) keeps
 the result bit-identical to ``blocking.quantize`` + ``blocking.dequantize``.
 
+The converter's output depends only on the activation tile ``(i, kk)``,
+not on the output tile ``j``.  So the kernel converts each activation tile
+once, at ``j == 0``, into a VMEM scratch that holds the decoded row block
+``(nk, TM, TK)`` in f32 (the operand the MXU saw before, so the result
+stays bit-identical), and every later ``j`` reads it back; x's index map
+stays on the last block fetched once ``j > 0``, so x streams from HBM
+once per row block.  The scratch takes ``K * TM * 4`` bytes (28.3 MB for a
+27648-wide K at TM = 256); the call raises its scoped VMEM limit by that
+much, and asserts that the row block fits the core's VMEM.
+
 Two static switches cover the training datapath:
 
   * ``emit_codes``: additionally write the LHS codes + scales (the packed
-    residual the custom-VJP backward needs).  The codes blocks are indexed
-    by (i, kk), so they are rewritten (with identical values) once per N
-    tile — cheap for N ~ TN; the unfused path's codes *read* in the matmul
-    is what the fusion always removes.
+    residual the custom-VJP backward needs), at ``j == 0`` with the row
+    block (their index maps, like x's, stay on the last block once
+    ``j > 0``, so each block is written back once).
   * ``quantize_lhs=False``: skip the converter and feed raw f32 (the
     ``quantize_bwd=False`` gradient path: unquantized g against packed w).
 
@@ -48,45 +57,51 @@ from .common import (block_exponents, decode_mxsf, encode_mxsf, exp2i,
 
 SCALE_BIAS = 127
 
+# VMEM of a v5e TensorCore.  The kernel's other buffers -- pipeline
+# buffers, accumulator, the converter's temporaries -- fit the 16 MiB
+# default scoped limit; a call asks for twice that on top of its resident
+# row block.
+VMEM_BYTES = 128 << 20
+OTHER_VMEM_BYTES = 32 << 20
+
 
 def _fused_kernel(x_ref, wc_ref, ws_ref, o_ref, *rest, nk: int, xblk, wblk,
                   quantize_lhs: bool, emit_codes: bool):
     if emit_codes:
-        xc_ref, xs_ref, acc_ref = rest
-    else:
-        (acc_ref,) = rest
+        xc_ref, xs_ref, *rest = rest
+    acc_ref, *rest = rest
+    kk = pl.program_id(2)
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-
     if quantize_lhs:
-        # --- MXSF Converter, fused into the matmul prologue ---------------
-        se, se_el = block_exponents(x, *xblk)
-        codes = encode_mxsf(scale_by_exp2(x, -se_el))
-        # decode-in-MAC: reconstruct through the byte codec so the operand
-        # is bit-identical to the packed reference path
-        xv = decode_mxsf(codes) * exp2i(se_el)
-        if emit_codes:
-            # The (i, kk) codes block changes every inner (K) step, so it is
-            # written back on every visit — including the revisits at j > 0,
-            # which rewrite identical values (N/TN-fold write amplification
-            # of the 1-byte residual on TPU).  Gating on j == 0 would be
-            # wrong: an unwritten revisited output block writes back
-            # undefined VMEM contents.  Residual-free callers (serving)
-            # should pass emit_codes=False.
-            xc_ref[...] = codes
-            xs_ref[...] = jnp.clip(se + SCALE_BIAS, 0, 255).astype(jnp.uint8)
+        (xq_ref,) = rest
+
+        @pl.when(pl.program_id(1) == 0)
+        def _convert():
+            # --- MXSF Converter, fused into the matmul prologue -----------
+            x = x_ref[...].astype(jnp.float32)
+            se, se_el = block_exponents(x, *xblk)
+            codes = encode_mxsf(scale_by_exp2(x, -se_el))
+            if emit_codes:
+                xc_ref[...] = codes
+                xs_ref[...] = jnp.clip(se + SCALE_BIAS, 0,
+                                       255).astype(jnp.uint8)
+            # decode-in-MAC: reconstruct through the byte codec so the
+            # operand is bit-identical to the packed reference path
+            xq_ref[kk] = decode_mxsf(codes) * exp2i(se_el)
+
+        xv = xq_ref[kk]
     else:
-        xv = x
+        xv = x_ref[...].astype(jnp.float32)
 
     wse = ws_ref[...].astype(jnp.int32) - SCALE_BIAS
     wv = decode_mxsf(wc_ref[...]) * exp2i(expand_scales(wse, *wblk))
     acc_ref[...] += jnp.dot(xv, wv, preferred_element_type=jnp.float32)
 
-    @pl.when(pl.program_id(2) == nk - 1)
+    @pl.when(kk == nk - 1)
     def _flush():
         o_ref[...] = acc_ref[...]
 
@@ -118,7 +133,12 @@ def mxsf_fused_matmul_pallas(x, w_codes, w_scales, *,
     kernel = functools.partial(_fused_kernel, nk=nk, xblk=xblk, wblk=wblk,
                                quantize_lhs=quantize_lhs,
                                emit_codes=emit_codes)
-    x_tile = lambda i, j, kk: (i, kk)
+    if quantize_lhs:
+        # once j > 0 the x (and emitted codes) block stays on the last one
+        # visited: no fetch of x, and the codes are written back once
+        x_tile = lambda i, j, kk: (i, jnp.where(j == 0, kk, nk - 1))
+    else:
+        x_tile = lambda i, j, kk: (i, kk)
     w_tile = lambda i, j, kk: (kk, j)
     out_shape = [jax.ShapeDtypeStruct((m, n), jnp.float32)]
     out_specs = [pl.BlockSpec((tm, tn), lambda i, j, kk: (i, j))]
@@ -131,6 +151,17 @@ def mxsf_fused_matmul_pallas(x, w_codes, w_scales, *,
             pl.BlockSpec((tm, tk), x_tile),
             scale_block_spec(xblk, tm, tk, x_tile),
         ]
+    scratch = [pltpu.VMEM((tm, tn), jnp.float32)]
+    params = None
+    if quantize_lhs:
+        row_block = tm * k * 4
+        assert row_block + OTHER_VMEM_BYTES <= VMEM_BYTES, (
+            f"decoded row block of {tm}x{k} takes {row_block} B of VMEM")
+        scratch.append(pltpu.VMEM((nk, tm, tk), jnp.float32))
+        # j carries the row block from j == 0 on: it stays sequential
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=OTHER_VMEM_BYTES + row_block)
     out = pl.pallas_call(
         kernel,
         grid=(m // tm, n // tn, nk),
@@ -141,7 +172,8 @@ def mxsf_fused_matmul_pallas(x, w_codes, w_scales, *,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+        scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
     )(x, w_codes, w_scales)
     return tuple(out) if emit_codes else out[0]

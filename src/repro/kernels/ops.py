@@ -30,6 +30,18 @@ from .mxsf_fused_matmul import mxsf_fused_matmul_pallas
 from .mxsf_quant import mxsf_quantize_pallas, mxsf_requantize_pallas
 
 
+# (activation tiles the fused matmul's converter runs on, grid steps) of
+# every mxsf_fused_matmul call, counted as it is traced (or run eagerly)
+_FUSED_TILES = [0, 0]
+
+
+def fused_lhs_converts():
+    """(activation tiles converted, grid steps) summed over the fused
+    matmul calls traced so far; the kernel converts each (row block, K
+    tile) once, whatever the number of output tiles along N."""
+    return tuple(_FUSED_TILES)
+
+
 def _interpret() -> bool:
     platform = jax.default_backend()
     if platform not in ("tpu", "cpu"):
@@ -170,6 +182,9 @@ def mxsf_fused_matmul(x, w_codes, w_scales, xblk=(1, 32), wblk=(32, 1),
     kblk = max(xblk[1], wblk[0])
     assert kblk % xblk[1] == 0 and kblk % wblk[0] == 0, (xblk, wblk)
     tk, kp = _tile_for(kw, tk, kblk, math.lcm(xc, wr))
+    mt, nk = mp // tm, kp // tk
+    _FUSED_TILES[0] += mt * nk if quantize_lhs else 0
+    _FUSED_TILES[1] += mt * (np_ // tn) * nk
     # no host-side upcast: the kernel casts per-tile in VMEM, so bf16
     # activations stream 2 bytes/elem from HBM, not 4
     out = mxsf_fused_matmul_pallas(

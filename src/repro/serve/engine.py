@@ -84,6 +84,7 @@ from ..core import packed_store
 from ..core import sharding as shd
 from ..core.blocking import QuantizedTensor
 from ..core.policy import QuantPolicy
+from ..kernels import ops as kernel_ops
 from ..launch import mesh as mesh_lib
 from ..models import model as M
 
@@ -252,8 +253,11 @@ class ServeEngine:
         def serve_prefill_step(p, t, c, pos, nv):
             return M.prefill_step(p, t, c, pos, nv, cfg, policy)
 
-        step = self._traced(serve_decode_step)
-        pre = self._traced(serve_prefill_step)
+        # (activation tiles converted, grid steps) of each step's fused
+        # matmuls, recorded when the step is traced
+        self._converts = {}
+        step = self._traced("decode", serve_decode_step)
+        pre = self._traced("prefill", serve_prefill_step)
         if self.rules is None:
             self._decode = jax.jit(step)
             self._prefill = jax.jit(pre) if chunk > 1 else None
@@ -304,19 +308,27 @@ class ServeEngine:
                 return False
         return True
 
-    def _traced(self, fn):
+    def _traced(self, phase: str, fn):
         """``fn`` traced inside ``sharding.mesh_context`` under a mesh, so
         the role hints in models/blocks.py resolve to mesh axes and the
         Pallas kernels run shard-local however the step is lowered
-        (dispatch or ahead of time); ``fn`` itself without a mesh."""
-        if self.rules is None:
-            return fn
-        mesh, dp, tp = self.mesh, self.rules.dp, self.rules.tp
+        (dispatch or ahead of time).  Each trace also records the fused
+        matmul's activation-tile conversions and grid steps in the step
+        (``lhs_convert_share`` in ``stats()``)."""
+        mesh = self.mesh
+        scope = contextlib.nullcontext
+        if self.rules is not None:
+            dp, tp = self.rules.dp, self.rules.tp
+            scope = lambda: shd.mesh_context(mesh, dp, tp)
 
         @functools.wraps(fn)
         def traced(*args):
-            with shd.mesh_context(mesh, dp, tp):
-                return fn(*args)
+            c0 = kernel_ops.fused_lhs_converts()
+            with scope():
+                out = fn(*args)
+            self._converts[phase] = [
+                b - a for a, b in zip(c0, kernel_ops.fused_lhs_converts())]
+            return out
 
         return traced
 
@@ -365,6 +377,13 @@ class ServeEngine:
           (replicated leaves count full-size on every device).
         * ``attn_backend`` / ``shard_fallback`` / ``mesh`` — which
           datapath engaged and why a kernel gate may have disengaged.
+        * ``lhs_convert_share`` — per compiled step (``decode`` /
+          ``prefill``), the activation tiles the fused matmuls convert
+          over their grid steps: 1/(N/TN) for a call with N/TN output
+          tiles, which all read one converted row block.  Each call site
+          traced in the step counts once (a layer scan's body once,
+          whatever its length).  Static per compiled shape; a step with no
+          fused matmul (jnp backend) is left out.
         """
         denom = self.ticks * self.slots
         return {
@@ -384,6 +403,8 @@ class ServeEngine:
             "store_nbytes": dict(self.store_nbytes),
             "store_nbytes_per_device": shd.per_device_nbytes(self.params),
             "cache_nbytes_per_device": shd.per_device_nbytes(self.cache),
+            "lhs_convert_share": {phase: conv / steps for phase, (conv, steps)
+                                  in self._converts.items() if steps},
         }
 
     def submit(self, prompt: List[int], max_new: int,

@@ -103,14 +103,30 @@ def test_dequant_matmul_compiles(one_chip, compiled_kernels, xblk, wblk):
     (SLOTS, D, FF, (1, 64), (64, 1), False),           # decode: wg/wu
     (SLOTS * CHUNK, FF, D, (1, 64), (64, 1), False),   # prefill chunk: wd
     (256, D, 1024, (8, 8), (8, 8), True),              # training forward
+    # the benchmark's largest linears, whose resident row blocks take the
+    # most VMEM: qwen2.5-32b's wd and head at 2048 prefill rows, danube's
+    # wd at 4096
+    (2048, FF, D, (1, 64), (64, 1), False),
+    (2048, D, 153600, (1, 64), (64, 1), False),
+    (4096, 6912, 2560, (1, 64), (64, 1), False),
 ])
 def test_fused_matmul_compiles(one_chip, compiled_kernels, m, k, n, xblk,
                                wblk, emit):
     args = [_spec(one_chip, (m, k), "bfloat16"),
             _spec(one_chip, (k, n), "uint8"),
             _spec(one_chip, (k // wblk[0], n // wblk[1]), "uint8")]
-    _compile(lambda *a: ops.mxsf_fused_matmul(*a, xblk, wblk,
-                                              emit_codes=emit), *args)
+    compiled = _compile(lambda *a: ops.mxsf_fused_matmul(
+        *a, xblk, wblk, emit_codes=emit), *args)
+    # the benchmark finds the kernel by this op name and reads (m, n) from
+    # its first output and (m, k) from its first operand
+    call = [ln for ln in compiled.as_text().splitlines()
+            if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert len(call) == 1, call
+    name, rest = call[0].split(" = ", 1)
+    assert name.split()[-1].startswith("%mxsf_fused_matmul_pallas."), name
+    np_ = -(-n // 256) * 256
+    assert rest.lstrip("(").startswith(f"f32[{m},{np_}]"), rest[:80]
+    assert f"operand_layout_constraints={{bf16[{m}," in rest, rest
 
 
 @pytest.mark.parametrize("s", [1, CHUNK])  # decode step, prefill chunk

@@ -134,6 +134,118 @@ def test_fused_matmul_bf16_input():
 
 
 # ---------------------------------------------------------------------------
+# grids with several tiles along M, N and K: the decoded row block is
+# converted at j == 0 into its kk slot and read back for every later j
+# ---------------------------------------------------------------------------
+
+# ((m, k, n), (tm, tn, tk)): tile-aligned, grid (2, 3, 2); padded on every
+# dim, grid (3, 3, 3) under both layouts
+GRIDS = [((32, 128, 96), (16, 32, 64)), ((17, 70, 33), (8, 16, 32))]
+
+
+def _exact(shape, axis, seed):
+    """Integers in [-16, 16] scaled by 2^e, one e per row (``axis=1``) or
+    column (``axis=0``): MXSF and bf16 hold them exactly, and every
+    product and partial sum of x @ w is exact in f32, so the result is
+    that of one contraction bit for bit in any K order."""
+    rng = np.random.default_rng(seed)
+    ints = rng.integers(-16, 17, shape).astype(np.float32)
+    e = rng.integers(-20, 21, shape[1 - axis])
+    return jnp.asarray(ints * np.expand_dims(2.0 ** e, axis)
+                       .astype(np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mkn,tiles", GRIDS, ids=["aligned", "padded"])
+@pytest.mark.parametrize("xblk,wblk", LAYOUTS, ids=["1d", "2d"])
+def test_fused_matmul_multi_tile_grid(xblk, wblk, mkn, tiles, dtype):
+    m, k, n = mkn
+    tm, tn, tk = tiles
+    x = _exact((m, k), 1, seed=40).astype(dtype)
+    w = _exact((k, n), 0, seed=41)
+    wc, ws = ops.mxsf_quantize(w, block=wblk)
+    kw = dict(tm=tm, tn=tn, tk=tk)
+    y = ops.mxsf_fused_matmul(x, wc, ws, xblk, wblk, **kw)
+    yr = ref.mxsf_fused_matmul_ref(x.astype(jnp.float32), wc, ws, xblk, wblk)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(yr)[:m])
+    y2, xc, xs = ops.mxsf_fused_matmul(x, wc, ws, xblk, wblk,
+                                       emit_codes=True, **kw)
+    np.testing.assert_array_equal(np.asarray(y2), np.asarray(y))
+    qt = B.quantize(x.astype(jnp.float32), "mxsf", xblk)
+    np.testing.assert_array_equal(np.asarray(xc), np.asarray(qt.codes))
+    np.testing.assert_array_equal(np.asarray(xs), np.asarray(qt.scale_e8m0))
+
+
+@pytest.mark.parametrize("xblk,wblk", LAYOUTS, ids=["1d", "2d"])
+def test_fused_matmul_multi_tile_edge_inputs(xblk, wblk):
+    """The edge rows (zeros, subnormals, S_e = 127) among random ones on a
+    (3, 3, 1) grid: every output tile past the first reads the decoded
+    row block back.  Each tile is held bitwise to the reference on that
+    tile's rows and columns (one contraction of the same shape, so the
+    overflowing partial sums of the edge rows round alike)."""
+    x = jnp.concatenate([_edge_rows(64), _rand((16, 64), seed=42)])
+    w = _rand((64, 48), seed=43)
+    wc, ws = ops.mxsf_quantize(w, block=wblk)
+    y, xc, xs = ops.mxsf_fused_matmul(x, wc, ws, xblk, wblk, tm=8, tn=16,
+                                      tk=64, emit_codes=True)
+    for i in range(0, 24, 8):
+        for j in range(0, 48, 16):
+            yr = ref.mxsf_fused_matmul_ref(
+                x[i:i + 8], wc[:, j:j + 16],
+                ws[:, j // wblk[1]:(j + 16) // wblk[1]], xblk, wblk)
+            np.testing.assert_array_equal(np.asarray(y[i:i + 8, j:j + 16]),
+                                          np.asarray(yr))
+    qt = B.quantize(x, "mxsf", xblk)
+    np.testing.assert_array_equal(np.asarray(xc), np.asarray(qt.codes))
+    np.testing.assert_array_equal(np.asarray(xs), np.asarray(qt.scale_e8m0))
+
+
+def test_fused_matmul_convert_count():
+    """A grid of (2, 3, 2) tiles converts (M/TM) * (K/TK) = 4 activation
+    tiles in its 12 grid steps, with or without emitted codes; one output
+    tile along N converts in every step, and quantize_lhs=False in none."""
+    x, w = _rand((32, 128), seed=44), _rand((128, 96), seed=45)
+    wc, ws = ops.mxsf_quantize(w, block=(32, 1))
+
+    def count(tn=32, **kw):
+        c0 = ops.fused_lhs_converts()
+        ops.mxsf_fused_matmul(x, wc, ws, (1, 32), (32, 1), tm=16, tn=tn,
+                              tk=64, **kw)
+        return tuple(b - a for a, b in zip(c0, ops.fused_lhs_converts()))
+
+    assert count() == (4, 12)
+    assert count(emit_codes=True) == (4, 12)
+    assert count(quantize_lhs=False) == (0, 12)
+    assert count(tn=96) == (4, 4)
+
+
+def test_kernel_opcount_converter_scales_with_rows():
+    """benchmarks/kernel_opcount.py: the converter's element-ops grow in
+    step with the activation tile's rows, the weight decode's not at all."""
+    from benchmarks.kernel_opcount import count
+
+    (a8, w8), (a16, w16), (a24, w24) = (count(tm, 128, 128)
+                                        for tm in (8, 16, 24))
+    assert a24 - a16 == a16 - a8 > 0
+    assert w8 == w16 == w24 > 0
+
+
+def test_fused_matmul_steps_tiny(capsys):
+    """benchmarks/fused_matmul_steps.py --tiny: one line per shape with the
+    grid's steps and the converter's count (one K tile, four N tiles: one
+    conversion in four steps)."""
+    from benchmarks.fused_matmul_steps import main
+
+    main(["--tiny"])
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("KB|")]
+    assert len(lines) == 2, lines
+    for ln in lines:
+        assert "|steps=4|counted=1/4|sums=" in ln, ln
+
+
+# ---------------------------------------------------------------------------
 # mx_dot backend="pallas" vs backend="jnp"
 # ---------------------------------------------------------------------------
 

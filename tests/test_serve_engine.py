@@ -140,6 +140,9 @@ def test_engine_pallas_packed_kv_matches_sequential():
     eng = ServeEngine(cfg, params, pol, slots=2, max_len=max_len,
                       backend="pallas")
     assert eng.attn_backend == "pallas-packed"
+    # count from cold caches: a test run earlier in this process may have
+    # traced the kernel at these shapes already
+    jax.clear_caches()
     traces0 = MA.trace_count()
     reqs = [eng.submit(p, max_new) for p in prompts]
     fin = eng.run()
@@ -194,3 +197,30 @@ def test_engine_pallas_packed_kv_matches_sequential():
     agree = float((jnp.argmax(lj, -1) == jnp.argmax(lp, -1)).mean())
     assert rel < 0.1, rel
     assert agree >= 0.8, agree
+
+
+def test_engine_reports_lhs_convert_share():
+    """stats()["lhs_convert_share"] per compiled step: the fused matmuls'
+    activation tiles converted over their grid steps, each call site
+    traced once (the layer scan's body once).  The layer's seven linears
+    have one N tile each (1 conversion in 1 step apiece); the 1024-wide
+    head has four, served by one converted row block: 8 / 11."""
+    from repro.core.policy import MXSF_INFER
+
+    cfg = get_config("qwen2.5-32b").reduced().replace(
+        compute_dtype="float32", n_layers=1, vocab=1024)
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    pol = MXSF_INFER.replace(block_1d=16, kv_cache_fmt="mxsf")
+    eng = ServeEngine(cfg, params, pol, slots=2, max_len=16,
+                      prefill_chunk=4, backend="pallas")
+    assert eng.stats()["lhs_convert_share"] == {}  # nothing traced yet
+    eng.submit([1, 2, 3, 4, 5, 6], 2)
+    eng.run()
+    assert eng.prefill_dispatches and eng.decode_dispatches
+    assert eng.stats()["lhs_convert_share"] == {"prefill": 8 / 11,
+                                                "decode": 8 / 11}
+    jnp_eng = ServeEngine(cfg, params, pol, slots=2, max_len=16,
+                          prefill_chunk=4)
+    jnp_eng.submit([1, 2, 3], 1)
+    jnp_eng.run()
+    assert jnp_eng.stats()["lhs_convert_share"] == {}  # no fused matmul
