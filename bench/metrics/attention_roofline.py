@@ -2,7 +2,8 @@
 chip's peaks over the device time of the ops under the program's
 ``attention`` scope.
 
-The work is ``attn_kernel_roofline``'s (``work.attn_rows``: live positions
+The work is ``attn_kernel_roofline``'s (``attn_rows`` of the
+configuration's architecture module; for a dense decoder, live positions
 read once per kv head, causal FLOPs; decode and prefill dispatches each
 bounded by the larger of compute and memory time).  The time is the union
 of the ops whose scope path runs through ``jax.named_scope("attention")``
@@ -28,7 +29,7 @@ def read(run):
     by_bound = {"compute": 0.0, "memory": 0.0}
     for t in run.ticks:
         for mask in (t.prefill, ~t.prefill):
-            f, b = work.attn_rows(s, t.starts[mask], t.rows[mask])
+            f, b = run.arch.attn_rows(s, t.starts[mask], t.rows[mask])
             if f == 0:
                 continue
             least, bound = work.least_time(f, b, run.peaks)

@@ -1,13 +1,14 @@
 """attn_kernel_roofline: least time the cached attention's work needs at
 the chip's peaks over the packed-KV attention kernel's summed device time.
 
-Work per dispatch and layer (``work.attn_rows``): the codes and scales of
-each slot's live positions read once per kv head, queries and outputs,
-and 4 * dh FLOPs per (query head, visible key) pair, counted causally
-within a prefill chunk.  Decode and prefill dispatches are separate
+Work per dispatch and layer (``attn_rows`` of the configuration's
+architecture module; for a dense decoder, ``work.attn_rows``): the codes and
+scales of each slot's live positions read once per kv head, queries and
+outputs, and 4 * dh FLOPs per (query head, visible key) pair, counted
+causally within a prefill chunk.  Decode and prefill dispatches are separate
 kernel calls, each bounded by the larger of compute and memory time.  The
-kernel's ops are named after its wrapper, ``_flash_attention_jit``.
-Layer: attention (``kernels/mxsf_attention.py``).
+kernel's ops are named after its wrapper, ``_flash_attention_jit``.  Layer:
+attention (``kernels/mxsf_attention.py``).
 """
 import trace_reduce as tr
 import work
@@ -24,7 +25,7 @@ def read(run):
     by_bound = {"compute": 0.0, "memory": 0.0}
     for t in run.ticks:
         for mask in (t.prefill, ~t.prefill):
-            f, b = work.attn_rows(s, t.starts[mask], t.rows[mask])
+            f, b = run.arch.attn_rows(s, t.starts[mask], t.rows[mask])
             if f == 0:
                 continue
             least, bound = work.least_time(f, b, run.peaks)

@@ -3,10 +3,11 @@ their memory analysis.  Nothing runs; no chip is needed.
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python bench/rehearse.py qwen32b-chat ...
 
-For each cell: the packed store's bytes, the packed cache's bytes, and per
-step the compiled argument, output and temporary bytes.  The engine's jit
-donates nothing, so a step's peak is about argument + output + temporary
-bytes.
+The weights are the configuration's architecture module's, packed as a run
+packs them.  For each cell: the packed store's bytes, the packed cache's
+bytes, and per step the compiled argument, output and temporary bytes.  The
+engine's jit donates nothing, so a step's peak is about argument + output +
+temporary bytes.
 """
 from __future__ import annotations
 
@@ -30,10 +31,13 @@ def rehearse(cell: str) -> dict:
 
     from repro.kernels import ops
     from repro.models import model as M
+    from weights import root_key
 
     c = spec.load_cell(cell)
-    cfg, policy = spec.program_config(c["config"]), spec.policy().replace(
+    A = spec.arch(c["config"])
+    cfg, policy = A.program_config(c["config"]), spec.policy().replace(
         backend="pallas")
+    s = A.sizes(c["config"])
     eng = c["engine"]
     slots, max_len, chunk = eng["slots"], eng["max_len"], eng["prefill_chunk"]
     topo = topologies.get_topology_desc(platform="tpu",
@@ -43,8 +47,9 @@ def rehearse(cell: str) -> dict:
     place = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
     params = place(jax.eval_shape(
-        lambda k: M.pack_model_params(cfg, M.init_params(k, cfg), policy),
-        jax.random.PRNGKey(0)))
+        lambda k: M.pack_model_params(
+            cfg, A.program_params(k, s, cfg.padded_vocab), policy),
+        root_key(0)))
     cache = place(jax.eval_shape(
         lambda: M.init_cache(cfg, slots, max_len, dtype=cfg.compute_dtype,
                              ring=False, kv_fmt=policy.kv_cache_fmt)))

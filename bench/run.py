@@ -10,7 +10,9 @@ traffic mix, parameters and per-layer metric readers are files under
    and fails, printing no result, unless JAX finds a TPU with as many chips
    as the cell asks for;
 2. makes the weights on the device from ``--seed`` and packs them into the
-   program's MXSF store, in one jitted call;
+   program's MXSF store, in one jitted call (the weights, the program
+   config, the plain reference and the work counts come from the
+   configuration's architecture module, ``spec.arch``);
 3. builds ``ServeEngine`` with the mix's engine shape and warms its two
    step shapes with one prefill and one decode dispatch (set-up ends here);
 4. drives the window through ``ServeEngine.submit`` and one-tick
@@ -20,7 +22,7 @@ traffic mix, parameters and per-layer metric readers are files under
    with the profiler and reads the per-layer metrics from it; otherwise
    reports the end-to-end metrics;
 6. frees the program's state and checks the served tokens against the
-   plain float32 reference (``reference.py``) on a sample of requests
+   architecture module's plain float32 reference on a sample of requests
    drawn from the seed.
 
 The last line of standard output is one JSON object: ``correct``,
@@ -118,23 +120,24 @@ def build(cell: dict, seed: int):
     in one jitted call."""
     import jax
 
-    import weights as W
     from repro.models import model as M
     from repro.serve.engine import ServeEngine
+    from weights import root_key
 
-    cfg = spec.program_config(cell["config"])
-    s = spec.sizes(cell["config"])
+    A = spec.arch(cell["config"])
+    cfg = A.program_config(cell["config"])
+    s = A.sizes(cell["config"])
     policy = spec.policy()
-    make = lambda key: W.program_params(key, s, cfg.padded_vocab)
-    ours = jax.eval_shape(make, W.root_key(0))
+    make = lambda key: A.program_params(key, s, cfg.padded_vocab)
+    ours = jax.eval_shape(make, root_key(0))
     theirs = jax.eval_shape(lambda k: M.init_params(k, cfg),
                             jax.random.PRNGKey(0))
     shape = lambda t: jax.tree.map(lambda a: (a.shape, str(a.dtype)), t)
     if shape(ours) != shape(theirs):
-        raise ValueError("bench/weights.py no longer makes the program's "
+        raise ValueError(f"{A.__file__} no longer makes the program's "
                          "parameter layout")
     params = jax.jit(lambda key: M.pack_model_params(cfg, make(key), policy))(
-        W.root_key(seed))
+        root_key(seed))
     jax.block_until_ready(params)
     e = cell["engine"]
     eng = ServeEngine(cfg, params, policy, backend="pallas",
@@ -336,9 +339,11 @@ def end_to_end(win: Window, setup_s: float) -> dict:
 @dataclasses.dataclass
 class Traced:
     """What the metric readers get: the traced ticks, the device ops and
-    host spans of the trace, its window, and the cell's sizes and peaks."""
+    host spans of the trace, its window, and the cell's sizes, architecture
+    module (whose work counts the readers take) and peaks."""
     cell: dict
     sizes: dict
+    arch: object
     peaks: dict
     slots: int
     chunk: int
@@ -361,7 +366,8 @@ def read_trace(win: Window, cell: dict, peaks: dict, eng) -> Traced:
         raise RuntimeError("the trace holds no device operations or no "
                            "ticks")
     ops = devs[sorted(devs)[0]]
-    return Traced(cell, spec.sizes(cell["config"]), peaks, eng.slots,
+    A = spec.arch(cell["config"])
+    return Traced(cell, A.sizes(cell["config"]), A, peaks, eng.slots,
                   eng.prefill_chunk, [t for t in win.ticks if t.traced],
                   ops, spans,
                   tick_spans[0][1], tick_spans[-1][2])
@@ -406,13 +412,13 @@ def check(cell: dict, seed: int, samples, control: bool = False):
     """Each number compared with its limit; with ``control``, the same
     numbers for the int4 control, read on the same positions (else
     None)."""
-    import reference
+    A = spec.arch(cell["config"])
     lim = cell["params"]["limits"]
     n = sum(len(o) for _, o in samples)
     gap = ctl = None
     if samples:
-        gaps, cgaps = reference.served_gaps(
-            spec.sizes(cell["config"]), seed, samples,
+        gaps, cgaps = A.served_gaps(
+            A.sizes(cell["config"]), seed, samples,
             cell["mix"]["check"]["ref_tokens"], control=control)
         gap = float(np.max(gaps))
         ctl = None if cgaps is None else float(np.max(cgaps))

@@ -6,7 +6,10 @@ cell, configuration, mix or metric by adding files and entries:
 
   * ``BENCHMARK.json`` (repository root): cells and metrics
   * ``bench/configs/<config>.json``: model sizes as published, the cut,
-    and the precision served
+    and the precision served; its ``"arch"`` names the architecture module
+  * ``bench/archs/<arch>.py`` (``"dense"`` when the config names none):
+    the configuration's sizes, program config, weights, plain reference
+    and work counts
   * ``bench/mixes/<traffic>.json``: traffic generator parameters and the
     engine shape
   * ``bench/cells/<workload>.json`` (optional): parameters of one cell,
@@ -17,24 +20,13 @@ cell, configuration, mix or metric by adding files and entries:
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-
-# program config field <- published (Hugging Face config.json) key
-PROGRAM_KEYS = {
-    "n_layers": "num_hidden_layers",
-    "d_model": "hidden_size",
-    "n_heads": "num_attention_heads",
-    "n_kv": "num_key_value_heads",
-    "d_head": "head_dim",
-    "d_ff": "intermediate_size",
-    "vocab": "vocab_size",
-    "rope_theta": "rope_theta",
-}
 
 
 def _json(path):
@@ -79,18 +71,37 @@ def load_cell(workload: str, bench: dict | None = None) -> dict:
     }
 
 
+@functools.lru_cache(maxsize=None)
+def _module(path: str, name: str):
+    """The module at ``path``, executed once per path."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str):
     """The ``read`` function of a per-layer metric's own file."""
     for stem in (metric, metric.split(".")[0]):
         path = os.path.join(HERE, "metrics", f"{stem}.py")
         if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _module(path,
+                           f"bench_metric_{stem.replace('.', '_')}").read
     raise FileNotFoundError(f"no reader for per-layer metric {metric!r} "
                             f"under {os.path.join(HERE, 'metrics')}")
+
+
+def arch(config: dict):
+    """The architecture module a configuration names with ``"arch"``
+    (``"dense"`` when it names none): ``bench/archs/<arch>.py``, loaded once
+    per file."""
+    name = config.get("arch", "dense")
+    where = os.path.join(HERE, "archs")
+    path = os.path.join(where, f"{name}.py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"no architecture module {name!r} under "
+                                f"{where}")
+    return _module(path, f"bench_arch_{name}")
 
 
 def peaks(device_kind: str) -> dict:
@@ -103,25 +114,12 @@ def peaks(device_kind: str) -> dict:
 
 def sizes(config: dict) -> dict:
     """The model sizes the reference and the work counts read."""
-    s = {k: config[k] for k in (
-        "num_hidden_layers", "hidden_size", "num_attention_heads",
-        "num_key_value_heads", "head_dim", "intermediate_size", "vocab_size",
-        "rope_theta", "rms_norm_eps", "attention_bias", "sliding_window")}
-    return s
+    return arch(config).sizes(config)
 
 
 def program_config(config: dict):
-    """The program's ModelConfig for a configuration file: the registry
-    entry named by ``model`` with the file's sizes applied."""
-    from repro.configs.base import get_config
-    base = get_config(config["model"])
-    kw = {field: config[key] for field, key in PROGRAM_KEYS.items()}
-    kw["qkv_bias"] = bool(config["attention_bias"])
-    if config["sliding_window"]:
-        kw.update(swa_window=config["sliding_window"], swa_pattern="all")
-    else:
-        kw.update(swa_window=None, swa_pattern="none")
-    return base.replace(name=config["name"], **kw)
+    """The program's ModelConfig for a configuration file."""
+    return arch(config).program_config(config)
 
 
 def policy():

@@ -65,7 +65,7 @@ def test_configs_match_the_program_and_list_every_cut():
             assert f["published"][key] != f[key]
         prog = spec.program_config(f)
         full = get_config(f["model"])
-        for field, key in spec.PROGRAM_KEYS.items():
+        for field, key in spec.arch(f).PROGRAM_KEYS.items():
             want = f["published"].get(key, f[key])
             assert getattr(full, field) == want, (c["name"], key)
             assert getattr(prog, field) == f[key]

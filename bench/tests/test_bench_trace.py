@@ -70,7 +70,8 @@ def _traced(loop):
                 np.array([True, False]), 0, 1, 1)
     t2 = R.Tick(0.06, 0.1, np.array([3, 0]), np.array([1, 0]),
                 np.array([False, False]), 1, 0, 1)
-    return R.Traced(cell, s, peaks, 2, 4, [t1, t2], OPS, SPANS, 0, 100 * MS)
+    return R.Traced(cell, s, spec.arch(cell["config"]), peaks, 2, 4,
+                    [t1, t2], OPS, SPANS, 0, 100 * MS)
 
 
 def test_useful_row_share():
